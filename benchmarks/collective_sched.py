@@ -65,19 +65,22 @@ def structural(full: bool = False):
                           + txt.count(" all-reduce-start("),
                           "all_gather": txt.count(" all-gather(")}))
     """)
+    # an HLO study on 8 virtual CPU devices by design: the child never
+    # touches an accelerator, which the parent process may hold
     env = {**os.environ, "PYTHONPATH": str(repo / "src"),
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, cwd=repo, timeout=900)
+    if r.returncode != 0:
+        raise RuntimeError(f"collective_structural child failed "
+                           f"(exit {r.returncode}):\n{r.stderr[-2000:]}")
     rows = []
     import json as _json
     for line in r.stdout.splitlines():
         line = line.strip()
         if line.startswith("{"):
             rows.append(_json.loads(line))
-    if r.returncode != 0:
-        rows.append({"mode": "ERROR", "all_reduce": -1,
-                     "all_gather": r.stderr[-200:]})
     emit("collective_structural", rows)
     return rows
 

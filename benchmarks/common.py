@@ -22,6 +22,7 @@ import hashlib
 import json
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from repro.core.sim import SimConfig, simulate, run_sweep
@@ -31,6 +32,7 @@ from repro.core.hostmodel import HostConfig
 from repro.core.workloads import WorkloadSpec, make_messages
 from repro.core import scenarios
 from repro.core.priorities import PriorityAllocation
+from repro.kernels.arbiter.dispatch import resolve_backend
 
 ART = Path(__file__).resolve().parents[1] / "artifacts" / "bench"
 ART.mkdir(parents=True, exist_ok=True)
@@ -125,12 +127,15 @@ def _point_table(pt: dict, p: dict):
 def _point_key(*, workload, protocol, load, seed, overcommit, alloc,
                unsched_limit_bytes, params, scenario=None, spec=None,
                host=None) -> tuple[dict, Path]:
+    # platform + resolved backend: a checkout copied to another machine
+    # must never serve one platform's (or backend's) results on another
     keyd = dict(workload=workload, protocol=protocol, load=load, seed=seed,
                 overcommit=overcommit, alloc=alloc, scenario=scenario,
                 ul=(unsched_limit_bytes if not isinstance(
-                    unsched_limit_bytes, np.ndarray) else "array"), **params)
-    # optional axes join the key ONLY when set, so every pre-existing
-    # cache file and committed baseline `params` dict keeps its hash
+                    unsched_limit_bytes, np.ndarray) else "array"),
+                platform=jax.default_backend(),
+                backend=resolve_backend(None), **params)
+    # optional axes join the key ONLY when set
     if spec is not None:
         keyd["spec"] = _spec_key(spec)
     if host is not None:

@@ -20,6 +20,8 @@ def main() -> None:
                     help="comma-separated harness names")
     args = ap.parse_args()
 
+    from repro.jax_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import paper_figs as F
     from benchmarks import collective_sched as C
     from benchmarks import fabric_figs as FF

@@ -63,7 +63,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.priorities import allocate_priorities
@@ -411,10 +410,10 @@ def _sweep_batch(cfg, proto, S_stack, aux_stack, n_sched: int,
         return local(S_stack, aux_stack)
     mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("runs",))
     P = PartitionSpec("runs")
-    # check_rep=False: pallas_call has no replication rule, and every
-    # array here is fully partitioned along "runs" anyway.
-    return shard_map(local, mesh=mesh, in_specs=(P, P),
-                     out_specs=P, check_rep=False)(S_stack, aux_stack)
+    # check_vma=False: pallas_call has no varying-manual-axes rule, and
+    # every array here is fully partitioned along "runs" anyway.
+    return jax.shard_map(local, mesh=mesh, in_specs=(P, P),
+                         out_specs=P, check_vma=False)(S_stack, aux_stack)
 
 
 # ============================================================== results ==

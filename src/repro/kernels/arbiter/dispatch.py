@@ -30,11 +30,11 @@ dimension. Padding values are chosen so padded entries can never win
 (``BIG`` priority / ``False`` eligibility / the ``NEG`` key sentinel —
 NOT zero, which is a legitimate key value).
 
-Interpret-mode selection (``resolve_interpret``): Pallas TPU kernels
-only compile on a TPU, so off-TPU the pallas backends auto-select
-``interpret=True`` — the kernel is traced into plain XLA ops and runs
-(and is tested) everywhere. ``SIM_PALLAS_INTERPRET=0|1`` overrides, so
-a TPU host can still benchmark the interpreted path.
+Interpret-mode selection (``resolve_interpret``): on a TPU the kernels
+compile (``tests/test_tpu_compile.py`` holds them to the v5e compiler);
+off-TPU the pallas backends auto-select ``interpret=True``, which traces
+each kernel into plain XLA ops so the CPU tests can run it. Interpret
+mode is for those tests only. ``SIM_PALLAS_INTERPRET=0|1`` overrides.
 """
 from __future__ import annotations
 
@@ -53,10 +53,13 @@ BACKENDS = ("reference", "pallas", "pallas_fused")
 _ROW_UNIT = 8          # TPU sublane multiple for int32 blocks
 _COL_UNIT = 128        # TPU lane multiple
 
-# operand-size ceiling for the no-grid fused kernel (whole arrays live
-# in VMEM simultaneously); beyond it dispatch falls back to the staged
-# per-stage kernels — still pallas, still bit-identical
-FUSED_VMEM_LIMIT_BYTES = 8 * 2 ** 20
+# operand bytes (fused_operand_bytes) above which fused_slot runs the
+# staged per-stage kernels instead — still pallas, still bit-identical.
+# The fused kernel holds whole arrays in VMEM. At fused.VMEM_BUDGET_BYTES
+# the v5e compiler took a paper-width slot of 45 MiB in both the single
+# and the batched form and refused the batched one at 58.5 MiB;
+# tests/test_tpu_compile.py compiles a slot of this size in both forms.
+FUSED_VMEM_LIMIT_BYTES = 32 * 2 ** 20
 
 
 def resolve_backend(name: str | None) -> str:
@@ -168,6 +171,19 @@ def pallas_topk(keys, K: int, *, interpret: bool = False):
     return _topk_normalize(vals[:H], idx[:H])
 
 
+def fused_operand_bytes(down=None, up=None, keys=None, K: int = 0) -> int:
+    """What :func:`fused_slot` weighs against ``FUSED_VMEM_LIMIT_BYTES``,
+    from the padded shapes: three 4-byte ``(rows, cols)`` arrays per drain
+    stage, the ``(H2, M)`` keys and the two ``(H2, K)`` top-K outputs."""
+    nbytes = 0
+    for shape in (down, up):
+        if shape is not None:
+            nbytes += 12 * shape[0] * shape[1]
+    if keys is not None:
+        nbytes += 4 * keys[0] * keys[1] + 8 * keys[0] * K
+    return nbytes
+
+
 def fused_slot(down=None, up=None, topk=None, *,
                interpret: bool | None = None):
     """The ``pallas_fused`` backend's per-slot entry point: pad every
@@ -187,19 +203,17 @@ def fused_slot(down=None, up=None, topk=None, *,
     interpret = resolve_interpret(interpret)
     d_pad = u_pad = k_pad = None
     K = 0
-    nbytes = 0
     if down is not None:
         d_pad, _ = pad_tiles(down, (BIG, BIG, False))
-        nbytes += sum(4 * a.size for a in d_pad)
     if up is not None:
         u_pad, _ = pad_tiles(up, (BIG, BIG, False))
-        nbytes += sum(4 * a.size for a in u_pad)
     if topk is not None:
         keys, K = topk
-        keys = pad_min_cols(keys, K)
-        (kp,), _ = pad_tiles((keys,), (NEG,))
-        k_pad = kp
-        nbytes += 4 * kp.size + 8 * kp.shape[0] * K
+        (k_pad,), _ = pad_tiles((pad_min_cols(keys, K),), (NEG,))
+    nbytes = fused_operand_bytes(
+        down=None if d_pad is None else d_pad[0].shape,
+        up=None if u_pad is None else u_pad[0].shape,
+        keys=None if k_pad is None else k_pad.shape, K=K)
     if nbytes > FUSED_VMEM_LIMIT_BYTES:
         out = {}
         if down is not None:
@@ -256,4 +270,5 @@ def topk(keys, K: int, *, backend: str = "reference",
 
 __all__ = ["BACKENDS", "resolve_backend", "resolve_interpret",
            "arbitrate", "topk", "fused_slot", "pad_tiles", "pad_min_cols",
-           "pallas_arbitrate", "pallas_topk", "FUSED_VMEM_LIMIT_BYTES"]
+           "pallas_arbitrate", "pallas_topk", "FUSED_VMEM_LIMIT_BYTES",
+           "fused_operand_bytes"]

@@ -13,15 +13,16 @@ stages that ``dispatch.py`` previously issued as separate kernels:
 The three stages are data-independent within a slot once hoisted to slot
 start (the sim enforces the delay preconditions that make the hoist
 bit-exact — see ``sim._fused_precompute`` and DESIGN.md §11), so the
-kernel simply runs them back to back on whole-array VMEM blocks: at
-simulator scale every operand fits VMEM comfortably, and fusing removes
-two of the three HBM round-trips plus two kernel launches per slot.
+kernel simply runs them back to back on whole-array VMEM blocks, which
+removes two of the three HBM round-trips plus two kernel launches per
+slot. Slots too large for VMEM (``dispatch.FUSED_VMEM_LIMIT_BYTES``) run
+the staged kernels instead.
 
 Each stage's math is the single-block execution of the corresponding
-staged kernel — same masked reductions, same first-occurrence tie
-breaks, same ``BIG``/``NEG`` sentinels — which is why fused == staged is
-bit-exact and not merely close (the reductions are reordered across
-*blocks*, never within a row).
+staged kernel — the same ``kernel.lex_argmin`` / ``kernel.topk_rounds``,
+same first-occurrence tie breaks, same ``BIG``/``NEG`` sentinels — which
+is why fused == staged is bit-exact and not merely close (the reductions
+are reordered across *blocks*, never within a row).
 
 Two entry points:
 
@@ -44,96 +45,63 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.arbiter.kernel import BIG, NEG
+from repro.kernels.arbiter.kernel import NEG, lex_argmin, topk_rounds
 
-
-# ----------------------------------------------------- stage primitives ----
-
-def _lex_argmin(prio, seq, elig):
-    """Single-block ``_arb_kernel`` math: strict-priority-then-FIFO winner
-    per row. Returns ``(best_prio, best_idx)``; ``(BIG, 0)`` when the row
-    has no eligible entry."""
-    p = jnp.where(elig, prio, BIG)
-    s = jnp.where(elig, seq, BIG)
-    pmin = jnp.min(p, axis=1)
-    s_cand = jnp.where(p == pmin[:, None], s, BIG)
-    idx = jnp.argmin(s_cand, axis=1).astype(jnp.int32)
-    return pmin, idx
-
-
-def _topk_rounds(keys, K: int):
-    """Single-block ``_topk_kernel`` math: K rounds of masked max with
-    first-occurrence extraction. The running-tops prefix sits before the
-    key columns exactly as in the staged kernel's concat, so tie-breaks
-    (lowest global column — ``lax.top_k`` stability) are identical."""
-    Hb, Mb = keys.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (Hb, Mb), 1)
-    cand_v = jnp.concatenate(
-        [jnp.full((Hb, K), NEG, jnp.int32), keys], axis=1)
-    cand_i = jnp.concatenate(
-        [jnp.full((Hb, K), -1, jnp.int32), col], axis=1)
-    tops_v = jnp.full((Hb, K), NEG, jnp.int32)
-    tops_i = jnp.full((Hb, K), -1, jnp.int32)
-    for r in range(K):
-        m = jnp.max(cand_v, axis=1)
-        is_m = cand_v == m[:, None]
-        first = is_m & (jnp.cumsum(is_m.astype(jnp.int32), axis=1) == 1)
-        tops_v = tops_v.at[:, r].set(m)
-        tops_i = tops_i.at[:, r].set(
-            jnp.max(jnp.where(first, cand_i, -1), axis=1))
-        cand_v = jnp.where(first, jnp.int32(NEG), cand_v)
-        cand_i = jnp.where(first, jnp.int32(-1), cand_i)
-    return tops_v, tops_i
+# scoped-VMEM limit of both kernel forms, whose operands and temporaries
+# all live in VMEM at once (a v5e has 128 MiB; the compiler's default
+# scope is far smaller). dispatch.FUSED_VMEM_LIMIT_BYTES is the largest
+# slot that compiles within it.
+VMEM_BUDGET_BYTES = 100 * 2 ** 20
 
 
 # ------------------------------------------------------------ the kernel ---
 
 def _fused_kernel(*refs, K: int, has_down: bool, has_up: bool,
-                  has_topk: bool, batched: bool):
-    """(*ins, *outs) refs in stage order. ``batched`` refs carry a
-    leading length-1 block axis (one grid program per batch element)."""
-    rd = (lambda r: r[0]) if batched else (lambda r: r[...])
-
-    def wr(r, v):
-        if batched:
-            r[0] = v
-        else:
-            r[...] = v
-
+                  has_topk: bool):
+    """(*ins, *outs) refs in stage order, each one run's whole arrays
+    (the batched form squeezes its run axis out of every block)."""
     n_in = 3 * has_down + 3 * has_up + has_topk
     ins, outs = refs[:n_in], refs[n_in:]
     i = o = 0
-    if has_down:
-        bp, bi = _lex_argmin(rd(ins[i]), rd(ins[i + 1]), rd(ins[i + 2]))
-        wr(outs[o], bp)
-        wr(outs[o + 1], bi)
-        i += 3
-        o += 2
-    if has_up:
-        bp, bi = _lex_argmin(rd(ins[i]), rd(ins[i + 1]), rd(ins[i + 2]))
-        wr(outs[o], bp)
-        wr(outs[o + 1], bi)
-        i += 3
-        o += 2
+    for present in (has_down, has_up):
+        if present:
+            pmin, _, idx = lex_argmin(ins[i][...], ins[i + 1][...],
+                                      ins[i + 2][...])
+            outs[o][...] = pmin
+            outs[o + 1][...] = idx
+            i += 3
+            o += 2
     if has_topk:
-        tv, ti = _topk_rounds(rd(ins[i]), K)
-        wr(outs[o], tv)
-        wr(outs[o + 1], ti)
+        keys = ins[i][...]
+        Hb = keys.shape[0]
+        # the staged kernel's first block, with empty running tops in
+        # front: same candidates, same tie-breaks
+        col = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+        tv, ti = topk_rounds(
+            jnp.concatenate([jnp.full((Hb, K), NEG, jnp.int32), keys],
+                            axis=1),
+            jnp.concatenate([jnp.full((Hb, K), -1, jnp.int32), col],
+                            axis=1), K)
+        outs[o][...] = tv
+        outs[o + 1][...] = ti
 
 
 def _out_shapes(arrays, K: int, has_down: bool, has_up: bool,
                 has_topk: bool):
-    """Logical (unbatched) output shapes in stage order."""
+    """Kernel output shapes (one run) in stage order. Per-row drain
+    results are ``(rows, 1)`` columns: the TPU tiles the last two block
+    dims, so a rank-1 ``(rows,)`` output cannot take a batched block."""
     shapes = []
     i = 0
     if has_down:
         H = arrays[i].shape[-2]
-        shapes += [(H,), (H,)]
+        shapes += [(H, 1), (H, 1)]
         i += 3
     if has_up:
         U = arrays[i].shape[-2]
-        shapes += [(U,), (U,)]
+        shapes += [(U, 1), (U, 1)]
         i += 3
     if has_topk:
         H2 = arrays[i].shape[-2]
@@ -141,27 +109,37 @@ def _out_shapes(arrays, K: int, has_down: bool, has_up: bool,
     return shapes
 
 
+def _squeeze_rows(outs, has_topk: bool):
+    """Kernel outputs -> the raw convention: drain results ``(..., rows)``,
+    top-K results ``(..., rows, K)``."""
+    n_drain = len(outs) - 2 * has_topk
+    return tuple(o[..., 0] if j < n_drain else o
+                 for j, o in enumerate(outs))
+
+
 def _call_single(arrays, K, has_down, has_up, has_topk, interpret):
     kernel = functools.partial(_fused_kernel, K=K, has_down=has_down,
-                               has_up=has_up, has_topk=has_topk,
-                               batched=False)
+                               has_up=has_up, has_topk=has_topk)
     out_shape = [jax.ShapeDtypeStruct(s, jnp.int32)
                  for s in _out_shapes(arrays, K, has_down, has_up,
                                       has_topk)]
     # no grid: one program, whole-array VMEM refs — dispatch.fused_slot
     # guarantees the operands fit (falls back to staged kernels otherwise)
-    return pl.pallas_call(kernel, out_shape=out_shape,
-                          interpret=interpret)(*arrays)
+    return pl.pallas_call(
+        kernel, out_shape=out_shape, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_BUDGET_BYTES),
+        name="fused_slot")(*arrays)
 
 
 def _call_batch(arrays, K, has_down, has_up, has_topk, interpret):
     B = arrays[0].shape[0]
     kernel = functools.partial(_fused_kernel, K=K, has_down=has_down,
-                               has_up=has_up, has_topk=has_topk,
-                               batched=True)
+                               has_up=has_up, has_topk=has_topk)
 
     def spec(shape):
-        return pl.BlockSpec((1,) + shape,
+        # run axis squeezed out: the kernel sees one run's whole arrays
+        return pl.BlockSpec((None,) + shape,
                             lambda b, nd=len(shape): (b,) + (0,) * nd)
 
     shapes = _out_shapes(arrays, K, has_down, has_up, has_topk)
@@ -173,6 +151,9 @@ def _call_batch(arrays, K, has_down, has_up, has_topk, interpret):
         out_shape=[jax.ShapeDtypeStruct((B,) + s, jnp.int32)
                    for s in shapes],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_BUDGET_BYTES),
+        name="fused_slot_batch",
     )(*arrays)
 
 
@@ -186,16 +167,16 @@ def _fused_fn(K: int, has_down: bool, has_up: bool, has_topk: bool,
 
     @jax.custom_batching.custom_vmap
     def fn(*arrays):
-        return tuple(_call_single(arrays, K, has_down, has_up, has_topk,
-                                  interpret))
+        return _squeeze_rows(_call_single(arrays, K, has_down, has_up,
+                                          has_topk, interpret), has_topk)
 
     @fn.def_vmap
     def _rule(axis_size, in_batched, *arrays):  # noqa: ANN001
         arrays = tuple(
             a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
             for a, b in zip(arrays, in_batched))
-        outs = tuple(_call_batch(arrays, K, has_down, has_up, has_topk,
-                                 interpret))
+        outs = _squeeze_rows(_call_batch(arrays, K, has_down, has_up,
+                                         has_topk, interpret), has_topk)
         return outs, tuple(True for _ in outs)
 
     return fn
@@ -241,8 +222,9 @@ def fused_slot_batch(down=None, up=None, keys=None, K: int = 0, *,
         arrays += list(up)
     if keys is not None:
         arrays.append(keys)
-    return tuple(_call_batch(tuple(arrays), K, down is not None,
-                             up is not None, keys is not None, interpret))
+    return _squeeze_rows(_call_batch(tuple(arrays), K, down is not None,
+                                     up is not None, keys is not None,
+                                     interpret), keys is not None)
 
 
 __all__ = ["fused_slot", "fused_slot_batch"]

@@ -28,6 +28,57 @@ BIG = 2 ** 30    # plain int: jnp constants would be captured as kernel operands
 NEG = -(2 ** 30)  # ineligible key sentinel: below every legitimate key (>= 0)
 
 
+# ------------------------------------------------- shared block math ------
+# Both the staged kernels below and the fused kernel (fused.py) run these
+# on one VMEM block. Every reduction keeps its row axis 2-D (``keepdims``)
+# and every tie-break is a masked ``min`` over a column ``iota``: the TPU
+# compiler lowers neither ``argmin`` on int32 nor ``cumsum`` nor the
+# scatter behind ``.at[].set``.
+
+def _first_col(hit, col):
+    """Per row, the lowest ``col`` where ``hit`` holds, as ``(rows, 1)``
+    (``BIG`` where nothing hits) — the first-occurrence rule of
+    ``argmin`` and of ``lax.top_k``'s stable ties."""
+    return jnp.min(jnp.where(hit, col, BIG), axis=1, keepdims=True)
+
+
+def lex_argmin(prio, seq, elig):
+    """Strict priority, then FIFO, over one ``(rows, cols)`` block.
+    Returns ``(pmin, smin, idx)``, each ``(rows, 1)``: the winning
+    priority and sequence (``BIG`` when the row has nothing eligible)
+    and the lowest block column holding that pair (0 when nothing)."""
+    p = jnp.where(elig, prio, BIG)
+    s = jnp.where(elig, seq, BIG)
+    pmin = jnp.min(p, axis=1, keepdims=True)
+    s_cand = jnp.where(p == pmin, s, BIG)
+    smin = jnp.min(s_cand, axis=1, keepdims=True)
+    col = jax.lax.broadcasted_iota(jnp.int32, s_cand.shape, 1)
+    return pmin, smin, _first_col(s_cand == smin, col)
+
+
+def topk_rounds(cand_v, cand_i, K: int):
+    """K rounds of masked max over candidate keys ``cand_v`` and their
+    source columns ``cand_i`` (both ``(rows, n)``). Each round takes the
+    FIRST occurrence of the row maximum, so ties resolve to the earliest
+    candidate column. ``NEG`` is the neutral "taken/absent" key — NOT
+    zero, which is a legitimate (ineligible) key value that must still
+    outrank padding. Returns ``(vals, idx)``, each ``(rows, K)``."""
+    rows = cand_v.shape[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1)
+    rank = jax.lax.broadcasted_iota(jnp.int32, (rows, K), 1)
+    tops_v = jnp.full((rows, K), NEG, jnp.int32)
+    tops_i = jnp.full((rows, K), -1, jnp.int32)
+    for r in range(K):
+        m = jnp.max(cand_v, axis=1, keepdims=True)
+        first = col == _first_col(cand_v == m, col)
+        src = jnp.max(jnp.where(first, cand_i, -1), axis=1, keepdims=True)
+        tops_v = jnp.where(rank == r, m, tops_v)
+        tops_i = jnp.where(rank == r, src, tops_i)
+        cand_v = jnp.where(first, NEG, cand_v)
+        cand_i = jnp.where(first, -1, cand_i)
+    return tops_v, tops_i
+
+
 # ------------------------------------------------------ priority arbiter ---
 
 def _arb_kernel(prio_ref, seq_ref, elig_ref, prio_out, idx_out,
@@ -41,15 +92,8 @@ def _arb_kernel(prio_ref, seq_ref, elig_ref, prio_out, idx_out,
         bs_scr[...] = jnp.full_like(bs_scr, BIG)
         bi_scr[...] = jnp.zeros_like(bi_scr)
 
-    elig = elig_ref[...]
-    p = jnp.where(elig, prio_ref[...], BIG)                 # (bh, bc)
-    s = jnp.where(elig, seq_ref[...], BIG)
-
-    # local lexicographic argmin within the block
-    pmin = jnp.min(p, axis=1)                               # (bh,)
-    s_cand = jnp.where(p == pmin[:, None], s, BIG)
-    smin = jnp.min(s_cand, axis=1)
-    col = jnp.argmin(s_cand, axis=1).astype(jnp.int32) + ci * bc
+    pmin, smin, col = lex_argmin(prio_ref[...], seq_ref[...], elig_ref[...])
+    col = col + ci * bc
 
     # merge with running best
     bp, bs = bp_scr[...], bs_scr[...]
@@ -75,22 +119,26 @@ def priority_arbiter(prio, seq, elig, *, block_h: int = 8,
     ncap = cap // bc
 
     kernel = functools.partial(_arb_kernel, bc=bc, ncap=ncap)
-    return pl.pallas_call(
+    # per-row results are (H, 1) columns: the TPU refuses rank-1 blocks
+    # narrower than 128 lanes
+    bp, bi = pl.pallas_call(
         kernel,
         grid=(H // bh, ncap),
         in_specs=[pl.BlockSpec((bh, bc), lambda hi, ci: (hi, ci)),
                   pl.BlockSpec((bh, bc), lambda hi, ci: (hi, ci)),
                   pl.BlockSpec((bh, bc), lambda hi, ci: (hi, ci))],
-        out_specs=[pl.BlockSpec((bh,), lambda hi, ci: (hi,)),
-                   pl.BlockSpec((bh,), lambda hi, ci: (hi,))],
-        out_shape=[jax.ShapeDtypeStruct((H,), jnp.int32),
-                   jax.ShapeDtypeStruct((H,), jnp.int32)],
+        out_specs=[pl.BlockSpec((bh, 1), lambda hi, ci: (hi, 0)),
+                   pl.BlockSpec((bh, 1), lambda hi, ci: (hi, 0))],
+        out_shape=[jax.ShapeDtypeStruct((H, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((H, 1), jnp.int32)],
         # NB: distinct scratch objects — a repeated instance would alias
-        scratch_shapes=[pltpu.VMEM((bh,), jnp.int32),
-                        pltpu.VMEM((bh,), jnp.int32),
-                        pltpu.VMEM((bh,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bh, 1), jnp.int32),
+                        pltpu.VMEM((bh, 1), jnp.int32),
+                        pltpu.VMEM((bh, 1), jnp.int32)],
         interpret=interpret,
+        name="priority_arbiter",
     )(prio, seq, elig)
+    return bp[:, 0], bi[:, 0]
 
 
 # ---------------------------------------------------------- SRPT top-K -----
@@ -105,28 +153,15 @@ def _topk_kernel(key_ref, val_out, idx_out, val_scr, idx_scr, *,
         idx_scr[...] = jnp.full_like(idx_scr, -1)
 
     k = key_ref[...]                                        # (bh, bm) int32
-    bh = k.shape[0]
-    col = (jax.lax.broadcasted_iota(jnp.int32, (bh, bm), 1)
+    col = (jax.lax.broadcasted_iota(jnp.int32, k.shape, 1)
            + mi * bm)                                       # global columns
-    # merge block into running top-K: combine candidates, extract K maxima.
-    # NEG is the neutral "taken/absent" sentinel — NOT zero, which is a
-    # legitimate (ineligible) key value that must still outrank padding.
-    # Extraction takes the FIRST occurrence of each maximum; running tops
-    # sit before block columns in the concat and block columns ascend, so
-    # ties resolve to the lowest global column — lax.top_k's stability.
-    cand_v = jnp.concatenate([val_scr[...], k], axis=1)     # (bh, K+bm)
-    cand_i = jnp.concatenate([idx_scr[...], col], axis=1)
-    tops_v, tops_i = val_scr[...], idx_scr[...]
-    for r in range(K):
-        m = jnp.max(cand_v, axis=1)                         # (bh,)
-        is_m = cand_v == m[:, None]
-        first = is_m & (jnp.cumsum(is_m.astype(jnp.int32), axis=1) == 1)
-        tops_v = tops_v.at[:, r].set(m)
-        tops_i = tops_i.at[:, r].set(
-            jnp.max(jnp.where(first, cand_i, -1), axis=1))
-        cand_v = jnp.where(first, jnp.int32(NEG), cand_v)
-        cand_i = jnp.where(first, jnp.int32(-1), cand_i)
-
+    # merge the block into the running top-K. The running tops sit before
+    # the block columns and block columns ascend, so first-occurrence
+    # extraction resolves ties to the lowest global column —
+    # lax.top_k's stability.
+    tops_v, tops_i = topk_rounds(
+        jnp.concatenate([val_scr[...], k], axis=1),
+        jnp.concatenate([idx_scr[...], col], axis=1), K)
     val_scr[...] = tops_v
     idx_scr[...] = tops_i
 
@@ -162,4 +197,5 @@ def srpt_topk(keys, K: int, *, block_h: int = 8, block_m: int = 512,
         scratch_shapes=[pltpu.VMEM((bh, K), jnp.int32),
                         pltpu.VMEM((bh, K), jnp.int32)],
         interpret=interpret,
+        name="srpt_topk",
     )(keys)

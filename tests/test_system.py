@@ -34,3 +34,24 @@ def test_end_to_end_homa_pipeline():
     assert p99 < 3.5, p99
     med = np.median(res.slowdown[res.done])
     assert med < 1.5, med
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """Entry scripts' compile cache: ``$JAX_COMPILATION_CACHE_DIR`` stands
+    untouched when set; otherwise one fixed directory in the checkout."""
+    import jax
+    from repro import jax_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert jax_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = jax_cache.enable_compile_cache()
+        assert path == str(jax_cache.CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert jax_cache.CHECKOUT_CACHE_DIR.name == ".jax_cache"
+        assert jax_cache.CHECKOUT_CACHE_DIR.parent.joinpath(
+            "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
