@@ -78,6 +78,11 @@ from repro.core.results import SimResult, bucketed_percentiles
 from repro.kernels.arbiter.dispatch import resolve_backend, \
     resolve_interpret
 
+# ``step_fn``'s stages, in slot order: the ``jax.named_scope`` each runs
+# under (a stage a configuration does not run emits no ops)
+STAGES = ("fused_precompute", "grants", "sender_select", "route",
+          "uplink_drain", "downlink_drain", "stats", "recovery",
+          "post_step", "telemetry")
 
 @dataclasses.dataclass(frozen=True)
 class SimConfig:
@@ -383,9 +388,14 @@ def _fused_precompute(cfg: SimConfig, proto: Protocol, S, n_sched: int,
 
 def step_fn(cfg: SimConfig, proto: Protocol, S, n_sched: int, st, now):
     """One link-time slot: policy-agnostic orchestration of receivers,
-    uplinks, the network, and the priority-queue downlinks."""
+    uplinks, the network, and the priority-queue downlinks.
+
+    Each stage runs under a ``jax.named_scope`` (``STAGES``, in slot
+    order), so its ops carry the stage name in their metadata and a
+    profiler trace of the scan can be split by stage."""
     H, cap, Dg = cfg.n_hosts, cfg.ring_cap, cfg.grant_delay_slots
     M = S["size"].shape[0]
+    scope = jax.named_scope
 
     # pre-step references for telemetry event deltas (DESIGN.md §8)
     tr_prev = telemetry.snapshot(cfg, st) if cfg.trace_on else None
@@ -394,42 +404,46 @@ def step_fn(cfg: SimConfig, proto: Protocol, S, n_sched: int, st, now):
     # arbitration (DESIGN.md §11); {} when nothing is fusable
     grant_st, fused = st, {}
     if cfg.fused_on:
-        st, grant_st, fused = _fused_precompute(cfg, proto, S, n_sched,
-                                                st, now)
+        with scope("fused_precompute"):
+            st, grant_st, fused = _fused_precompute(cfg, proto, S, n_sched,
+                                                    st, now)
 
     # ---- 1. receiver policy (current state), store into delay history
-    grant_r, sched_prio, active, withheld = proto.receiver.grants(
-        cfg, grant_st, S, now, n_sched, topk=fused.get("topk"))
-    st = {**st, "grant_r": grant_r, "sched_prio": sched_prio}
-    hist_grant = st["hist_grant"].at[now % Dg].set(grant_r)
-    hist_prio = st["hist_prio"].at[now % Dg].set(sched_prio)
-    # sender sees the entry written Dg-1 slots ago
-    vis_idx = (now + 1) % Dg
-    grant_vis = hist_grant[vis_idx]
-    prio_vis = hist_prio[vis_idx]
+    with scope("grants"):
+        grant_r, sched_prio, active, withheld = proto.receiver.grants(
+            cfg, grant_st, S, now, n_sched, topk=fused.get("topk"))
+        st = {**st, "grant_r": grant_r, "sched_prio": sched_prio}
+        hist_grant = st["hist_grant"].at[now % Dg].set(grant_r)
+        hist_prio = st["hist_prio"].at[now % Dg].set(sched_prio)
+        # sender sees the entry written Dg-1 slots ago
+        vis_idx = (now + 1) % Dg
+        grant_vis = hist_grant[vis_idx]
+        prio_vis = hist_prio[vis_idx]
 
-    arrived = S["arrival"] <= now
-    blind = jnp.where(arrived, S["unsched"], 0)
-    granted_s = jnp.maximum(jnp.maximum(st["granted_s"], blind), grant_vis)
-    st = {**st, "granted_s": granted_s, "hist_grant": hist_grant,
-          "hist_prio": hist_prio,
-          "sched_prio": jnp.where(arrived, prio_vis, st["sched_prio"])}
-    # NOTE: sender uses delayed sched_prio (the grant packet's priority)
+        arrived = S["arrival"] <= now
+        blind = jnp.where(arrived, S["unsched"], 0)
+        granted_s = jnp.maximum(jnp.maximum(st["granted_s"], blind),
+                                grant_vis)
+        st = {**st, "granted_s": granted_s, "hist_grant": hist_grant,
+              "hist_prio": hist_prio,
+              "sched_prio": jnp.where(arrived, prio_vis, st["sched_prio"])}
+        # NOTE: sender uses delayed sched_prio (the grant packet's priority)
 
     # ---- 2. senders pick + transmit one chunk (sender policy)
-    chosen, has = _sender_select(cfg, proto, st, S, now)
-    if cfg.host_tx_on:
-        # host/NIC stage (DESIGN.md §10): the selected chunk only makes
-        # the wire if the host's TX CPU budget covers it this slot
-        has, st = cfg.host_model.host_tx(cfg, st, has, now)
-    cm = jnp.minimum(chosen, M - 1)
-    unsched_chunk = st["sent"][cm] < S["unsched"][cm]
-    prio_chunk = proto.sender.chunk_prio(cfg, st, S, cm, unsched_chunk,
-                                         n_sched)
-    sent = st["sent"].at[cm].add(jnp.where(has, 1, 0), mode="drop")
-    st = {**st, "sent": sent,
-          "uplink_busy": st["uplink_busy"] + has.astype(I32)}
-    st = proto.sender.on_send(cfg, st, S, cm, has, now)
+    with scope("sender_select"):
+        chosen, has = _sender_select(cfg, proto, st, S, now)
+        if cfg.host_tx_on:
+            # host/NIC stage (DESIGN.md §10): the selected chunk only
+            # makes the wire if the host's TX CPU budget covers it
+            has, st = cfg.host_model.host_tx(cfg, st, has, now)
+        cm = jnp.minimum(chosen, M - 1)
+        unsched_chunk = st["sent"][cm] < S["unsched"][cm]
+        prio_chunk = proto.sender.chunk_prio(cfg, st, S, cm, unsched_chunk,
+                                             n_sched)
+        sent = st["sent"].at[cm].add(jnp.where(has, 1, 0), mode="drop")
+        st = {**st, "sent": sent,
+              "uplink_busy": st["uplink_busy"] + has.astype(I32)}
+        st = proto.sender.on_send(cfg, st, S, cm, has, now)
 
     # ---- 3. route chunks into the first queueing tier. Single switch:
     # straight into the destination downlink ring (true occupancy-based
@@ -437,94 +451,107 @@ def step_fn(cfg: SimConfig, proto: Protocol, S, n_sched: int, st, now):
     # Leaf-spine fabric: same-rack chunks switch at the leaf, cross-rack
     # chunks enter their TOR's hashed uplink queue, and each uplink
     # drains one chunk per slot toward the destination downlink.
-    dsts = jnp.where(has, S["dst"][cm], H)                   # sentinel H
-    if not cfg.fabric_on:
-        r_msg, r_prio, r_seq, r_valid, n_drop = ring_insert(
-            st["r_msg"], st["r_prio"], st["r_seq"], st["r_valid"],
-            dsts, has, cm, prio_chunk, jnp.full_like(dsts, now))
-        st = {**st, "r_msg": r_msg, "r_prio": r_prio, "r_seq": r_seq,
-              "r_valid": r_valid, "lost": st["lost"] + n_drop}
-    else:
-        st = route_chunks(cfg, st, S, cm, has, dsts, prio_chunk, now)
-        st = uplink_drain(cfg, st, S, now, pre=fused.get("up"))
+    with scope("route"):
+        dsts = jnp.where(has, S["dst"][cm], H)               # sentinel H
+        if not cfg.fabric_on:
+            r_msg, r_prio, r_seq, r_valid, n_drop = ring_insert(
+                st["r_msg"], st["r_prio"], st["r_seq"], st["r_valid"],
+                dsts, has, cm, prio_chunk, jnp.full_like(dsts, now))
+            st = {**st, "r_msg": r_msg, "r_prio": r_prio, "r_seq": r_seq,
+                  "r_valid": r_valid, "lost": st["lost"] + n_drop}
+        else:
+            st = route_chunks(cfg, st, S, cm, has, dsts, prio_chunk, now)
+    if cfg.fabric_on:
+        with scope("uplink_drain"):
+            st = uplink_drain(cfg, st, S, now, pre=fused.get("up"))
 
     # ---- 4. downlink drain: strict priority, FIFO within level
     # (backend-dispatched: cfg.backend="pallas" runs the priority_arbiter
     # kernel, bit-identical to the reference math — DESIGN.md §6)
-    eligible = st["r_valid"] & (st["r_seq"] + cfg.net_delay_slots <= now)
-    if cfg.faults_on and cfg.fabric.faults.tor_fail:
-        # hosts behind a failed TOR drain nothing for the window; their
-        # buffered chunks survive and resume draining when it lifts
-        eligible = eligible & ~host_down_mask(cfg, now)[:, None]
-    q_eligible = eligible                       # backlog incl. stalled rows
-    if "down" in fused:
-        # winner pre-solved at slot start by the fused kernel (incl. the
-        # RX delivery / room gate — _fused_precompute); this slot's
-        # insertions carry seq == now and can't be eligible yet, so the
-        # hoisted selection is bit-identical (DESIGN.md §11). q_eligible
-        # above is provably the kernel's pre-room eligibility input.
-        slot_idx, any_elig, pmin = fused["down"]
-    else:
+    with scope("downlink_drain"):
+        eligible = st["r_valid"] & (st["r_seq"] + cfg.net_delay_slots
+                                    <= now)
+        if cfg.faults_on and cfg.fabric.faults.tor_fail:
+            # hosts behind a failed TOR drain nothing for the window;
+            # their buffered chunks survive and resume when it lifts
+            eligible = eligible & ~host_down_mask(cfg, now)[:, None]
+        q_eligible = eligible                   # backlog incl. stalled rows
+        if "down" in fused:
+            # winner pre-solved at slot start by the fused kernel (incl.
+            # the RX delivery / room gate — _fused_precompute); this
+            # slot's insertions carry seq == now and can't be eligible
+            # yet, so the hoisted selection is bit-identical (DESIGN.md
+            # §11). q_eligible above is provably the kernel's pre-room
+            # eligibility input.
+            slot_idx, any_elig, pmin = fused["down"]
+        else:
+            if cfg.host_rx_on:
+                # host/NIC RX stage (DESIGN.md §10): finish service on
+                # ring entries whose CPU time elapsed (feeds recv ->
+                # grants AND completions), then gate the downlink on
+                # RX-ring room — a full ring backpressures the network
+                # (chunks stay queued, not lost)
+                hm = cfg.host_model
+                st = hm.rx_deliver(cfg, st, S, now)
+                room = hm.rx_room(cfg, st)
+                st = {**st, "h_rx_stall": st["h_rx_stall"]
+                      + (eligible.any(axis=1) & ~room).astype(I32)}
+                eligible = eligible & room[:, None]
+            slot_idx, any_elig, pmin = drain_select(
+                st["r_prio"], st["r_seq"], eligible, backend=cfg.backend,
+                interpret=cfg.pallas_interpret)
+        hidx = (jnp.arange(H), slot_idx)
+        drained_msg = jnp.where(any_elig, st["r_msg"][hidx], M)
         if cfg.host_rx_on:
-            # host/NIC RX stage (DESIGN.md §10): finish service on ring
-            # entries whose CPU time elapsed (feeds recv -> grants AND
-            # completions), then gate the downlink on RX-ring room — a
-            # full ring backpressures the network (chunks stay queued,
-            # not lost)
-            hm = cfg.host_model
-            st = hm.rx_deliver(cfg, st, S, now)
-            room = hm.rx_room(cfg, st)
-            st = {**st, "h_rx_stall": st["h_rx_stall"]
-                  + (eligible.any(axis=1) & ~room).astype(I32)}
-            eligible = eligible & room[:, None]
-        slot_idx, any_elig, pmin = drain_select(
-            st["r_prio"], st["r_seq"], eligible, backend=cfg.backend,
-            interpret=cfg.pallas_interpret)
-    hidx = (jnp.arange(H), slot_idx)
-    drained_msg = jnp.where(any_elig, st["r_msg"][hidx], M)
-    if cfg.host_rx_on:
-        # drained chunks enter the RX ring; recv advances in rx_deliver
-        st = cfg.host_model.rx_accept(cfg, st, S, drained_msg, any_elig,
-                                      now)
-        recv = st["recv"]
-    else:
-        recv = st["recv"].at[jnp.minimum(drained_msg, M - 1)].add(
-            jnp.where(any_elig, 1, 0), mode="drop")
-    r_valid = st["r_valid"].at[hidx].set(
-        jnp.where(any_elig, False, st["r_valid"][hidx]))
-    st = proto.on_drain(cfg, st, S, drained_msg, any_elig, now)
-
-    completion = jnp.where((recv >= S["size"]) & (st["completion"] < 0),
-                           now, st["completion"])
+            # drained chunks enter the RX ring; recv advances in rx_deliver
+            st = cfg.host_model.rx_accept(cfg, st, S, drained_msg,
+                                          any_elig, now)
+            recv = st["recv"]
+        else:
+            recv = st["recv"].at[jnp.minimum(drained_msg, M - 1)].add(
+                jnp.where(any_elig, 1, 0), mode="drop")
+        r_valid = st["r_valid"].at[hidx].set(
+            jnp.where(any_elig, False, st["r_valid"][hidx]))
+        st = proto.on_drain(cfg, st, S, drained_msg, any_elig, now)
 
     # ---- 5. stats
-    qlen = (q_eligible.sum(axis=1) - any_elig.astype(I32))
-    drained_prio = jnp.where(any_elig, jnp.minimum(
-        pmin, cfg.n_prios - 1), 0)
-    prio_drained = st["prio_drained"].at[drained_prio].add(
-        jnp.where(any_elig, 1, 0), mode="drop")
-    known_inc = (recv > 0) & (completion < 0)
-    has_known = (S["dst_onehot"] & known_inc[None, :]).any(axis=1)
-    wasted = st["wasted"] + (~any_elig & withheld & has_known).astype(I32)
+    with scope("stats"):
+        completion = jnp.where((recv >= S["size"]) & (st["completion"] < 0),
+                               now, st["completion"])
+        qlen = (q_eligible.sum(axis=1) - any_elig.astype(I32))
+        drained_prio = jnp.where(any_elig, jnp.minimum(
+            pmin, cfg.n_prios - 1), 0)
+        prio_drained = st["prio_drained"].at[drained_prio].add(
+            jnp.where(any_elig, 1, 0), mode="drop")
+        known_inc = (recv > 0) & (completion < 0)
+        has_known = (S["dst_onehot"] & known_inc[None, :]).any(axis=1)
+        wasted = st["wasted"] + (~any_elig & withheld
+                                 & has_known).astype(I32)
 
-    st = {**st, "recv": recv, "r_valid": r_valid, "completion": completion,
-          "busy": st["busy"] + any_elig.astype(I32),
-          "q_sum": st["q_sum"] + qlen.astype(jnp.float32),
-          "q_max": jnp.maximum(st["q_max"], qlen),
-          "wasted": wasted, "prio_drained": prio_drained}
+        st = {**st, "recv": recv, "r_valid": r_valid,
+              "completion": completion,
+              "busy": st["busy"] + any_elig.astype(I32),
+              "q_sum": st["q_sum"] + qlen.astype(jnp.float32),
+              "q_max": jnp.maximum(st["q_max"], qlen),
+              "wasted": wasted, "prio_drained": prio_drained}
 
     # ---- 5b. loss recovery (fault-enabled fabrics only, DESIGN.md §7):
     # receiver RESENDs + sender fallback timeouts rewind quiet messages'
     # send offsets so fault-dropped chunks get retransmitted
     if cfg.faults_on:
-        st = apply_recovery(cfg, proto, st, S, now, drained_msg, any_elig)
+        with scope("recovery"):
+            st = apply_recovery(cfg, proto, st, S, now, drained_msg,
+                                any_elig)
 
     # ---- 6. protocol end-of-slot hook (e.g. pHost sender timeouts)
-    st = proto.post_step(cfg, st, S, now, active, drained_msg, any_elig)
+    with scope("post_step"):
+        st = proto.post_step(cfg, st, S, now, active, drained_msg, any_elig)
 
     # ---- 7. telemetry capture (ledger append + strided series rows)
     if cfg.trace_on:
-        st = telemetry.capture_slot(cfg, st, S, now, tr_prev, active, qlen)
+        with scope("telemetry"):
+            st = telemetry.capture_slot(cfg, st, S, now, tr_prev, active,
+                                        qlen)
 
     return st, None
 
@@ -644,26 +671,39 @@ def simulate(cfg: SimConfig, table: MessageTable,
              return_state: bool = False) -> SimResult:
     """Run one simulation; returns a structured :class:`SimResult`.
 
-    With ``cfg.trace = TraceConfig(wallclock=True)`` the scan runs
-    through jax's AOT path and the exact trace / compile / execute
-    wall-clock split lands in ``result.trace.timings``."""
+    The call's host work is recorded as spans (``telemetry.span``): one
+    ``sim.simulate`` (counter ``slots``) holding ``sim.prepare``,
+    ``sim.init_state``, ``sim.dispatch``, ``sim.scan_wait``,
+    ``sim.fetch`` and ``sim.finalize``. With ``cfg.trace =
+    TraceConfig(wallclock=True)`` the scan runs through jax's AOT path
+    instead (``sim.lower`` / ``sim.compile`` / ``sim.execute``) and the
+    split lands in ``result.trace.timings``."""
     proto = get_protocol(cfg.protocol)
-    S, alloc = prepare(cfg, table, alloc, unsched_limit_bytes)
-    n_sched = proto.n_sched(cfg, alloc)
-    st0 = _init_state(cfg, proto, len(table.size))
-    timings = None
-    if cfg.trace is not None and cfg.trace.wallclock:
-        # wallclock instrumentation works with capture disabled too
-        # (TraceConfig(enabled=False, wallclock=True)): the timings of
-        # the UNTRACED program, for capture-overhead measurement
-        st, timings = telemetry.timed_aot_run(
-            _run, (cfg, proto, S, st0, n_sched), (S, st0),
-            repeats=cfg.trace.wallclock_repeats)
-    else:
-        st = _run(cfg, proto, S, st0, n_sched)
-    st = jax.tree.map(np.asarray, st)
-    return _finalize(cfg, table, S, alloc, st, return_state,
-                     timings=timings)
+    span = telemetry.span
+    with span("sim.simulate", slots=cfg.max_slots):
+        with span("sim.prepare"):
+            S, alloc = prepare(cfg, table, alloc, unsched_limit_bytes)
+            n_sched = proto.n_sched(cfg, alloc)
+        with span("sim.init_state"):
+            st0 = _init_state(cfg, proto, len(table.size))
+        timings = None
+        if cfg.trace is not None and cfg.trace.wallclock:
+            # wallclock instrumentation works with capture disabled too
+            # (TraceConfig(enabled=False, wallclock=True)): the timings
+            # of the UNTRACED program, for capture-overhead measurement
+            st, timings = telemetry.timed_aot_run(
+                _run, (cfg, proto, S, st0, n_sched), (S, st0),
+                repeats=cfg.trace.wallclock_repeats)
+        else:
+            with span("sim.dispatch"):
+                st = _run(cfg, proto, S, st0, n_sched)
+            with span("sim.scan_wait"):
+                jax.block_until_ready(st)
+        with span("sim.fetch"):
+            st = jax.tree.map(np.asarray, st)
+        with span("sim.finalize"):
+            return _finalize(cfg, table, S, alloc, st, return_state,
+                             timings=timings)
 
 
 def run_sweep(cfg: SimConfig, spec) -> list:

@@ -300,15 +300,16 @@ def _fold_hist(stream: StreamSpec, acc, st, S, aux, lo, hi):
     slots are immutable once set, so across chunk folds every message is
     counted exactly once."""
     B, K = stream.n_buckets, stream.n_size_buckets
-    comp = st["completion"]
-    m = (comp >= lo) & (comp < hi) & aux["counted"]
-    sd = (comp - S["arrival"] + 1).astype(jnp.float32) \
-        / S["ideal"].astype(jnp.float32)
-    b = jnp.searchsorted(jnp.asarray(sd_bucket_edges(stream)), sd,
-                         side="right")
-    flat = aux["szb"] * B + jnp.clip(b, 0, B - 1)
-    return acc + jax.ops.segment_sum(m.astype(I32), flat,
-                                     num_segments=K * B)
+    with jax.named_scope("stream_fold"):
+        comp = st["completion"]
+        m = (comp >= lo) & (comp < hi) & aux["counted"]
+        sd = (comp - S["arrival"] + 1).astype(jnp.float32) \
+            / S["ideal"].astype(jnp.float32)
+        b = jnp.searchsorted(jnp.asarray(sd_bucket_edges(stream)), sd,
+                             side="right")
+        flat = aux["szb"] * B + jnp.clip(b, 0, B - 1)
+        return acc + jax.ops.segment_sum(m.astype(I32), flat,
+                                         num_segments=K * B)
 
 
 def _device_summary(cfg, st, acc) -> dict:
@@ -590,76 +591,90 @@ def run_spec(cfg, spec: SweepSpec) -> list:
     """Execute a :class:`SweepSpec`: prepare, group by static scan
     parameters, shard/chunk/stream as configured, gather, and finalize —
     results in input order. (Public entry point: ``run_sweep(cfg,
-    spec)``; see that docstring for semantics.)"""
+    spec)``; see that docstring for semantics.) The host work is recorded
+    as spans (``telemetry.span``): one ``sweep.run`` (counter ``slots``:
+    runs x ``max_slots``) holding ``sweep.prepare`` and, per group,
+    ``sweep.stack``, ``sweep.dispatch``, ``sweep.scan_wait``,
+    ``sweep.fetch`` and ``sweep.stats``."""
     from repro.core import sim as sim_mod
-    tables = spec.resolve_tables(cfg)
-    if not tables:
-        return []
-    proto = get_protocol(cfg.protocol)
-    N = len(tables)
-    stream = spec.stream
+    span = telemetry.span
+    with span("sweep.run") as run:
+        with span("sweep.prepare"):
+            tables = spec.resolve_tables(cfg)
+            if not tables:
+                return []
+            proto = get_protocol(cfg.protocol)
+            N = len(tables)
+            stream = spec.stream
 
-    alloc = spec.alloc
-    if spec.shared_alloc and alloc is None:
-        alloc = allocate_priorities(
-            np.concatenate([t.size for t in tables]),
-            unsched_limit=cfg.rtt_bytes, n_prios=cfg.n_prios)
-    allocs = list(alloc) if isinstance(alloc, (list, tuple)) \
-        else [alloc] * N
-    uls = list(spec.unsched_limit_bytes) \
-        if isinstance(spec.unsched_limit_bytes, (list, tuple)) \
-        else [spec.unsched_limit_bytes] * N
-    if len(allocs) != N or len(uls) != N:
-        raise ValueError("per-table alloc/unsched_limit lists must match "
-                         "the number of tables")
+            alloc = spec.alloc
+            if spec.shared_alloc and alloc is None:
+                alloc = allocate_priorities(
+                    np.concatenate([t.size for t in tables]),
+                    unsched_limit=cfg.rtt_bytes, n_prios=cfg.n_prios)
+            allocs = list(alloc) if isinstance(alloc, (list, tuple)) \
+                else [alloc] * N
+            uls = list(spec.unsched_limit_bytes) \
+                if isinstance(spec.unsched_limit_bytes, (list, tuple)) \
+                else [spec.unsched_limit_bytes] * N
+            if len(allocs) != N or len(uls) != N:
+                raise ValueError("per-table alloc/unsched_limit lists must "
+                                 "match the number of tables")
 
-    prepped = []
-    for t, al_i, ul_i in zip(tables, allocs, uls):
-        S, al = sim_mod.prepare(cfg, t, al_i, ul_i)
-        prepped.append((S, al, proto.n_sched(cfg, al)))
+            prepped = []
+            for t, al_i, ul_i in zip(tables, allocs, uls):
+                S, al = sim_mod.prepare(cfg, t, al_i, ul_i)
+                prepped.append((S, al, proto.n_sched(cfg, al)))
+        run["counts"]["slots"] = N * cfg.max_slots
 
-    groups = group_runs([(len(t.size), ns)
-                         for t, (_, _, ns) in zip(tables, prepped)])
-    n_dev = resolve_devices(spec.shard)
-    fast = n_dev == 1 and spec.chunk_slots is None and stream is None
+        groups = group_runs([(len(t.size), ns)
+                             for t, (_, _, ns) in zip(tables, prepped)])
+        n_dev = resolve_devices(spec.shard)
+        fast = n_dev == 1 and spec.chunk_slots is None and stream is None
 
-    results: list = [None] * N
-    for (_, n_sched), idxs in groups.items():
-        if fast:
-            # the pre-SweepSpec program, byte for byte: one vmapped jit
-            # per group, full states gathered (bit-identity anchor)
-            S_stack = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                   *[prepped[i][0] for i in idxs])
-            st_batch = jax.tree.map(
-                np.asarray,
-                sim_mod._run_batch(cfg, proto, S_stack, n_sched))
-            out_rows = idxs
-        else:
-            pad = (-len(idxs)) % n_dev
-            padded = idxs + [idxs[-1]] * pad
-            S_stack = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                   *[prepped[i][0] for i in padded])
-            aux_stack = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[_pack_aux(stream, tables[i]) for i in padded]) \
-                if stream is not None else {}
-            st_batch = jax.tree.map(
-                np.asarray,
-                _sweep_batch(cfg, proto, S_stack, aux_stack, n_sched,
-                             spec.chunk_slots, stream, n_dev))
-            out_rows = idxs          # padding rows simply never read
+        def stack(rows):
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
 
-        for k, i in enumerate(out_rows):
-            row = jax.tree.map(lambda x: x[k], st_batch)
-            if stream is not None:
-                results[i] = _stats_from_row(cfg, stream, row,
-                                             prepped[i][1],
-                                             len(tables[i].size))
+        results: list = [None] * N
+        for (_, n_sched), idxs in groups.items():
+            if fast:
+                # the pre-SweepSpec program, byte for byte: one vmapped
+                # jit per group, full states gathered (bit-identity
+                # anchor)
+                with span("sweep.stack"):
+                    S_stack = stack([prepped[i][0] for i in idxs])
+                with span("sweep.dispatch"):
+                    out = sim_mod._run_batch(cfg, proto, S_stack, n_sched)
             else:
-                results[i] = sim_mod._finalize(
-                    cfg, tables[i], prepped[i][0], prepped[i][1], row,
-                    spec.return_state, reduce_trace=True)
-    return results
+                pad = (-len(idxs)) % n_dev
+                padded = idxs + [idxs[-1]] * pad
+                with span("sweep.stack"):
+                    S_stack = stack([prepped[i][0] for i in padded])
+                    aux_stack = stack([_pack_aux(stream, tables[i])
+                                       for i in padded]) \
+                        if stream is not None else {}
+                with span("sweep.dispatch"):
+                    out = _sweep_batch(cfg, proto, S_stack, aux_stack,
+                                       n_sched, spec.chunk_slots, stream,
+                                       n_dev)
+            with span("sweep.scan_wait"):
+                jax.block_until_ready(out)
+            with span("sweep.fetch"):
+                st_batch = jax.tree.map(np.asarray, out)
+
+            # padding rows (sharded groups) are simply never read
+            with span("sweep.stats"):
+                for k, i in enumerate(idxs):
+                    row = jax.tree.map(lambda x: x[k], st_batch)
+                    if stream is not None:
+                        results[i] = _stats_from_row(
+                            cfg, stream, row, prepped[i][1],
+                            len(tables[i].size))
+                    else:
+                        results[i] = sim_mod._finalize(
+                            cfg, tables[i], prepped[i][0], prepped[i][1],
+                            row, spec.return_state, reduce_trace=True)
+        return results
 
 
 __all__ = ["SweepSpec", "StreamSpec", "SweepStats", "run_spec",

@@ -29,11 +29,15 @@ events fall off and ``events_dropped`` counts them — capture stays
 jit-safe and bounded no matter how eventful the run is. Rows are
 recorded in slot order.
 
-**3. Host wall-clock.** ``TraceConfig(wallclock=True)`` makes
-``simulate`` run the scan through the AOT path (``jit.lower`` →
-``.compile()`` → execute) and records the exact trace / compile /
-execute split in ``SimTrace.timings``; benchmark cells surface the same
-split (``benchmarks/roofline.py`` backend cell, ``trace_smoke``).
+**3. Host wall-clock.** :func:`span` times the host side of
+``simulate`` and ``run_sweep`` on every call: named, nested spans kept in
+a bounded in-memory record (:func:`host_spans`, the last ``SPAN_CAP``)
+and entered as ``jax.profiler.TraceAnnotation`` so that each one lands
+in a profiler trace on the device ops' clock. ``TraceConfig(wallclock=
+True)`` makes ``simulate`` run the scan through the AOT path
+(``jit.lower`` → ``.compile()`` → execute, the ``sim.lower`` /
+``sim.compile`` / ``sim.execute`` spans) and records the split in
+``SimTrace.timings``.
 
 ``SimConfig.trace=None`` (the default) and ``TraceConfig(enabled=False)``
 keep the scan free of every array and op defined here: the untraced
@@ -50,8 +54,13 @@ loadable in https://ui.perfetto.dev), :meth:`SimTrace.to_timeseries_json`
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import itertools
 import json
+import statistics
+import threading
 import time
 from typing import Any
 
@@ -86,9 +95,9 @@ class TraceConfig:
     stride: int = 16                # slots per time-series sample window
     ledger_cap: int = 4096          # event rows kept; 0 disables the ledger
     wallclock: bool = False         # exact AOT trace/compile/execute split
-    wallclock_repeats: int = 1      # execute N times, report the min
-    #   (best-of-N suppresses shared-machine noise; the scan is
-    #   deterministic, so repeats change nothing but the timing)
+    wallclock_repeats: int = 1      # execute N times, report each and
+    #   their median (the scan is deterministic, so repeats change
+    #   nothing but the timing)
 
     def validate(self) -> None:
         if self.stride < 1:
@@ -483,35 +492,88 @@ def reduce_state(cfg, st: dict) -> dict:
 
 # ------------------------------------------------------------- wall clock --
 
+SPAN_CAP = 4096                    # host spans kept, newest last
+
+_SPANS: collections.deque = collections.deque(maxlen=SPAN_CAP)
+_OPEN = threading.local()          # this thread's stack of open spans
+_CALLS = itertools.count()
+
+
+@contextlib.contextmanager
+def span(name: str, **counts):
+    """Time a block of host work as a named span.
+
+    The span enters ``jax.profiler.TraceAnnotation(name)``, so a profiler
+    trace shows it on the same clock as the device ops, and on exit it
+    appends ``{name, start_ns, end_ns, parent, call, counts}`` to the
+    process's bounded span record (``time.perf_counter_ns``). ``parent``
+    is the name of the enclosing span; ``call`` is shared by a root span
+    and everything nested in it. The yielded record is the one appended,
+    so a caller may read its times after the block or add counts that
+    are known only inside it."""
+    stack = _OPEN.__dict__.setdefault("stack", [])
+    parent = stack[-1] if stack else None
+    rec = {"name": name, "start_ns": 0, "end_ns": 0,
+           "parent": parent["name"] if parent else None,
+           "call": parent["call"] if parent else next(_CALLS),
+           "counts": counts}
+    stack.append(rec)
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            rec["start_ns"] = time.perf_counter_ns()
+            try:
+                yield rec
+            finally:
+                rec["end_ns"] = time.perf_counter_ns()
+    finally:
+        stack.pop()
+        _SPANS.append(rec)
+
+
+def host_spans() -> list[dict]:
+    """The recorded host spans, oldest first (children before parents:
+    a span is recorded when it ends)."""
+    return list(_SPANS)
+
+
+def clear_spans() -> None:
+    _SPANS.clear()
+
+
 def timed_aot_run(jit_fn, all_args: tuple, dynamic_args: tuple,
                   repeats: int = 1) -> tuple[Any, dict]:
     """Run a jitted function through the AOT path and return
-    ``(result, timings)`` with the exact trace / compile / execute split
-    in seconds. ``all_args`` is the full positional argument list (as
-    the jitted function would be called); ``dynamic_args`` are the
-    non-static subset, in order, passed again at execute.
-    ``repeats > 1`` executes the compiled program N times and reports
-    the MINIMUM execute time (best-of-N: robust to machine noise; only
-    meaningful for deterministic functions)."""
-    t0 = time.perf_counter()
-    lowered = jit_fn.lower(*all_args)
-    t1 = time.perf_counter()
-    compiled = lowered.compile()
-    t2 = time.perf_counter()
+    ``(result, timings)`` with the trace / compile / execute split in
+    seconds, each phase also a span (``sim.lower``, ``sim.compile``, one
+    ``sim.execute`` per repeat). ``all_args`` is the full positional
+    argument list (as the jitted function would be called);
+    ``dynamic_args`` are the non-static subset, in order, passed again
+    at execute. ``repeats > 1`` executes the compiled program N times:
+    ``execute_each_s`` lists every repeat and ``execute_s`` is their
+    median (only meaningful for deterministic functions)."""
+    def secs(rec):
+        return (rec["end_ns"] - rec["start_ns"]) / 1e9
+
+    with span("sim.lower") as lo:
+        lowered = jit_fn.lower(*all_args)
+    with span("sim.compile") as co:
+        compiled = lowered.compile()
     execs = []
     for _ in range(max(repeats, 1)):
-        te = time.perf_counter()
-        out = compiled(*dynamic_args)
-        jax.block_until_ready(out)
-        execs.append(time.perf_counter() - te)
-    return out, {"trace_s": round(t1 - t0, 4),
-                 "compile_s": round(t2 - t1, 4),
-                 "execute_s": round(min(execs), 4),
+        with span("sim.execute") as ex:
+            out = compiled(*dynamic_args)
+            jax.block_until_ready(out)
+        execs.append(secs(ex))
+    return out, {"trace_s": round(secs(lo), 4),
+                 "compile_s": round(secs(co), 4),
+                 "execute_s": round(statistics.median(execs), 4),
+                 "execute_each_s": [round(e, 4) for e in execs],
                  "execute_repeats": len(execs)}
 
 
 __all__ = ["TraceConfig", "SimTrace", "init_trace_state", "snapshot",
            "capture_slot", "finalize_trace", "reduce_state",
-           "timed_aot_run", "n_samples",
+           "timed_aot_run", "n_samples", "span", "host_spans",
+           "clear_spans", "SPAN_CAP",
            "EV_GRANT", "EV_PREEMPT", "EV_LOSS", "EV_OVERFLOW", "EV_RESEND",
            "EV_TIMEOUT", "EV_COMPLETE", "EV_NAMES", "EV_COLUMNS"]

@@ -331,12 +331,23 @@ def test_trace_config_coerced_from_dict():
 
 def test_wallclock_reports_aot_split():
     """wallclock=True runs the scan through the AOT path and attaches
-    the trace/compile/execute split — with capture on or off."""
+    the trace/compile/execute split — with capture on or off. Every
+    repeat is reported, ``execute_s`` is their median, and each phase
+    is a span of the host span record."""
     r_on = _traced_run(n_messages=30, max_slots=500, trace=TraceConfig(
         stride=64, ledger_cap=32, wallclock=True))
     t = r_on.trace.timings
     assert set(t) >= {"trace_s", "compile_s", "execute_s"}
+    telemetry.clear_spans()
     r_off = _traced_run(n_messages=30, max_slots=500, trace=TraceConfig(
-        enabled=False, wallclock=True, wallclock_repeats=2))
+        enabled=False, wallclock=True, wallclock_repeats=3))
     t2 = r_off.trace_summary["timings"]
-    assert r_off.trace is None and t2["execute_repeats"] == 2
+    assert r_off.trace is None and t2["execute_repeats"] == 3
+    assert len(t2["execute_each_s"]) == 3
+    assert t2["execute_s"] == sorted(t2["execute_each_s"])[1]
+    spans = telemetry.host_spans()
+    assert [s["name"] for s in spans] == [
+        "sim.prepare", "sim.init_state", "sim.lower", "sim.compile",
+        "sim.execute", "sim.execute", "sim.execute", "sim.fetch",
+        "sim.finalize", "sim.simulate"]
+    assert {s["parent"] for s in spans[:-1]} == {"sim.simulate"}
