@@ -35,7 +35,8 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.arbiter.dispatch import topk as backend_topk
+from repro.kernels.arbiter.dispatch import (topk as backend_topk,
+                                            topk_rounds as backend_topk_rounds)
 
 I32 = jnp.int32
 BIG = jnp.int32(2 ** 30)
@@ -126,6 +127,12 @@ class ReceiverPolicy:
         the state :meth:`grants` reads, so solving it at slot start is
         bit-identical to solving it inside :meth:`grants`."""
         return None
+
+    def topk_rounds(self, cfg, n_sched, M):
+        """Selection rounds a slot's grant top-K over M messages runs
+        (``dispatch.topk_rounds``): 0 for a policy that selects no grant
+        set, or where the reference top-K sorts."""
+        return 0
 
     def resend(self, cfg, st, S, now, known, quiet):
         """Receiver-side loss detection (paper §3.7): (M,) bool mask of
@@ -263,6 +270,11 @@ class OvercommitSrptReceiver(ReceiverPolicy):
     def grant_problem(self, cfg, st, S, now, n_sched):
         return srpt_grant_matrix(cfg, st, S, self._eligible(cfg, st, now),
                                  self._k(cfg, n_sched))
+
+    def topk_rounds(self, cfg, n_sched, M):
+        # K clamped to M as srpt_grant_matrix clamps it
+        return backend_topk_rounds(min(self._k(cfg, n_sched), M), M,
+                                   cfg.backend)
 
     def resend(self, cfg, st, S, now, known, quiet):
         # Homa's receiver timeout (paper §3.7): a receiver that actively

@@ -672,7 +672,8 @@ def simulate(cfg: SimConfig, table: MessageTable,
     """Run one simulation; returns a structured :class:`SimResult`.
 
     The call's host work is recorded as spans (``telemetry.span``): one
-    ``sim.simulate`` (counter ``slots``) holding ``sim.prepare``,
+    ``sim.simulate`` (counters ``slots`` and ``grant_topk_rounds``, the
+    selection rounds of the grant top-K per slot) holding ``sim.prepare``,
     ``sim.init_state``, ``sim.dispatch``, ``sim.scan_wait``,
     ``sim.fetch`` and ``sim.finalize``. With ``cfg.trace =
     TraceConfig(wallclock=True)`` the scan runs through jax's AOT path
@@ -680,10 +681,12 @@ def simulate(cfg: SimConfig, table: MessageTable,
     split lands in ``result.trace.timings``."""
     proto = get_protocol(cfg.protocol)
     span = telemetry.span
-    with span("sim.simulate", slots=cfg.max_slots):
+    with span("sim.simulate", slots=cfg.max_slots) as top:
         with span("sim.prepare"):
             S, alloc = prepare(cfg, table, alloc, unsched_limit_bytes)
             n_sched = proto.n_sched(cfg, alloc)
+        top["counts"]["grant_topk_rounds"] = proto.receiver.topk_rounds(
+            cfg, n_sched, len(table.size))
         with span("sim.init_state"):
             st0 = _init_state(cfg, proto, len(table.size))
         timings = None

@@ -592,10 +592,11 @@ def run_spec(cfg, spec: SweepSpec) -> list:
     parameters, shard/chunk/stream as configured, gather, and finalize —
     results in input order. (Public entry point: ``run_sweep(cfg,
     spec)``; see that docstring for semantics.) The host work is recorded
-    as spans (``telemetry.span``): one ``sweep.run`` (counter ``slots``:
-    runs x ``max_slots``) holding ``sweep.prepare`` and, per group,
-    ``sweep.stack``, ``sweep.dispatch``, ``sweep.scan_wait``,
-    ``sweep.fetch`` and ``sweep.stats``."""
+    as spans (``telemetry.span``): one ``sweep.run`` (counters ``slots``:
+    runs x ``max_slots``; ``grant_topk_rounds``: the grant top-K's
+    selection rounds per slot, the most of any group) holding
+    ``sweep.prepare`` and, per group, ``sweep.stack``, ``sweep.dispatch``,
+    ``sweep.scan_wait``, ``sweep.fetch`` and ``sweep.stats``."""
     from repro.core import sim as sim_mod
     span = telemetry.span
     with span("sweep.run") as run:
@@ -626,6 +627,9 @@ def run_spec(cfg, spec: SweepSpec) -> list:
                 S, al = sim_mod.prepare(cfg, t, al_i, ul_i)
                 prepped.append((S, al, proto.n_sched(cfg, al)))
         run["counts"]["slots"] = N * cfg.max_slots
+        run["counts"]["grant_topk_rounds"] = max(
+            proto.receiver.topk_rounds(cfg, ns, len(t.size))
+            for t, (_, _, ns) in zip(tables, prepped))
 
         groups = group_runs([(len(t.size), ns)
                              for t, (_, _, ns) in zip(tables, prepped)])

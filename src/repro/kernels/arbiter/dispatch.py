@@ -38,6 +38,7 @@ mode is for those tests only. ``SIM_PALLAS_INTERPRET=0|1`` overrides.
 """
 from __future__ import annotations
 
+import math
 import os
 from functools import partial
 
@@ -46,7 +47,8 @@ import jax.numpy as jnp
 
 from repro.kernels.arbiter.kernel import (priority_arbiter, srpt_topk,
                                           BIG, NEG)
-from repro.kernels.arbiter.ref import priority_arbiter_ref, srpt_topk_ref
+from repro.kernels.arbiter.ref import (priority_arbiter_ref, srpt_topk_ref,
+                                      srpt_topk_rounds)
 from repro.kernels.arbiter import fused as fused_mod
 
 BACKENDS = ("reference", "pallas", "pallas_fused")
@@ -60,6 +62,28 @@ _COL_UNIT = 128        # TPU lane multiple
 # and the batched form and refused the batched one at 58.5 MiB;
 # tests/test_tpu_compile.py compiles a slot of this size in both forms.
 FUSED_VMEM_LIMIT_BYTES = 32 * 2 ** 20
+
+# The reference top-K of (H, M) keys either runs K read-only selection
+# rounds (ref.srpt_topk_rounds) or lax.top_k, which XLA lowers to a full
+# sort of every row whatever K (ref.srpt_topk_ref). topk_rounds picks
+# from the static K and M. One jitted top-K of a (144, M) int32 matrix
+# built as srpt_grant_matrix builds it, us per call on one TPU v5e
+# (a loop of 200 calls in one jit; lax.top_k's time does not move with K):
+#
+#      M  lax.top_k  rounds, K: us
+#    128        7.7  4: 8.4   6: 10.4   8: 12.1
+#    256       16.9  7: 11.1  13: 16.8  16: 19.3
+#    512       29.5  1: 5.8   7: 11.2   16: 19.8  32: 34.1  64: 63.3
+#   1024       56.5  32: 39.3  40: 47.8  48: 55.2
+#   2048      114.6  48: 84.5  59: 101.2  64: 109.4  81: 136.5
+#   6000        679  1: 12.5  7: 37.5  16: 77.5  32: 142.5  128: 539.4
+#   8192        588  64: 353.2  92: 501.9  104: 566.6  128: 693.3
+#
+# A round costs about 1 us up to M = 1024, 1.6 us at 2048 and 4-5 us at
+# 6000-8192; the sort grows as M log2(M) (5-9 ns x M log2(M), most at M = 6000). The
+# crossovers lie near K = 3, 13, 27, 49, 67, 160 and 108 at the M above.
+# The rule K (M + 2000) <= 9 M log2(M) stays under every one of them:
+# rounds for K <= 3, 8, 16, 30, 50, 84 and 94.
 
 
 def resolve_backend(name: str | None) -> str:
@@ -257,18 +281,39 @@ def arbitrate(prio, seq, elig, *, backend: str = "reference",
                             interpret=resolve_interpret(interpret))
 
 
+def topk_rounds(K: int, M: int, backend: str = "reference") -> int:
+    """Selection rounds that :func:`topk` of ``(H, M)`` keys runs on
+    ``backend``: K where it selects by rounds (the pallas kernels always,
+    the reference path up to the crossover measured above), 0 where it
+    sorts. The reference path of :func:`topk` takes its branch from this
+    function, so a count read from it cannot disagree with the program."""
+    if K < 1:
+        return 0
+    if resolve_backend(backend) != "reference":
+        return K
+    return K if M > 1 and K * (M + 2000) <= 9 * M * math.log2(M) else 0
+
+
 def topk(keys, K: int, *, backend: str = "reference",
          interpret: bool | None = None):
     """Per-row top-K keys + source columns on the chosen backend.
     Returns ``(vals (H, K), idx (H, K))``: descending keys clamped at 0,
     columns -1 where fewer than K positive keys exist. Ties resolve to
-    the lowest column on both backends (``lax.top_k`` stability)."""
+    the lowest column on every backend (``lax.top_k`` stability).
+
+    The reference backend has two forms, bit-identical to each other:
+    K read-only selection rounds (``ref.srpt_topk_rounds``), whose cost
+    grows as K x H x M, and ``lax.top_k`` (``ref.srpt_topk_ref``, the
+    test oracle), a full sort of every row whatever K. The static K
+    picks between them (:func:`topk_rounds`)."""
     if resolve_backend(backend) == "reference":
+        if topk_rounds(K, keys.shape[1]):
+            return srpt_topk_rounds(keys, K)
         return srpt_topk_ref(keys, K)
     return pallas_topk(keys, K, interpret=resolve_interpret(interpret))
 
 
 __all__ = ["BACKENDS", "resolve_backend", "resolve_interpret",
-           "arbitrate", "topk", "fused_slot", "pad_tiles", "pad_min_cols",
-           "pallas_arbitrate", "pallas_topk", "FUSED_VMEM_LIMIT_BYTES",
-           "fused_operand_bytes"]
+           "arbitrate", "topk", "topk_rounds", "fused_slot", "pad_tiles",
+           "pad_min_cols", "pallas_arbitrate", "pallas_topk",
+           "FUSED_VMEM_LIMIT_BYTES", "fused_operand_bytes"]

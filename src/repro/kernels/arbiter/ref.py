@@ -34,3 +34,32 @@ def srpt_topk_ref(keys, K: int):
     vals, idx = lax.top_k(keys, K)
     return (jnp.maximum(vals, 0).astype(jnp.int32),
             jnp.where(vals > 0, idx.astype(jnp.int32), -1))
+
+
+def srpt_topk_rounds(keys, K: int):
+    """:func:`srpt_topk_ref` without the sort: K unrolled selection
+    rounds, bit-identical to it for every input. Round r takes each
+    row's greatest (key, then lowest column) among the entries strictly
+    after round r-1's winner ``(v, c)`` — ``key < v``, or ``key == v``
+    and ``col > c`` — starting from ``(INT32_MAX, -1)``. Only the
+    ``(H, 1)`` winner is carried between rounds, so a round reads the
+    keys once and writes nothing back to the ``(H, M)`` matrix."""
+    if keys.shape[1] < K:
+        keys = jnp.pad(keys, ((0, 0), (0, K - keys.shape[1])),
+                       constant_values=NEG)
+    H, M = keys.shape
+    lo, hi = jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max
+    col = lax.broadcasted_iota(jnp.int32, (H, M), 1)
+    v = jnp.full((H, 1), hi, jnp.int32)
+    c = jnp.full((H, 1), -1, jnp.int32)
+    vals, idx = [], []
+    for _ in range(K):
+        after = (keys < v) | ((keys == v) & (col > c))
+        v = jnp.max(jnp.where(after, keys, lo), axis=1, keepdims=True)
+        c = jnp.min(jnp.where(after & (keys == v), col, M), axis=1,
+                    keepdims=True)
+        vals.append(v)
+        idx.append(c)
+    vals = jnp.concatenate(vals, axis=1)
+    idx = jnp.concatenate(idx, axis=1)
+    return jnp.maximum(vals, 0), jnp.where(vals > 0, idx, -1)

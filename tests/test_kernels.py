@@ -12,7 +12,8 @@ from repro.kernels.ssd.ops import ssd
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.arbiter import dispatch
 from repro.kernels.arbiter import ops as arb_ops
-from repro.kernels.arbiter.ref import priority_arbiter_ref, srpt_topk_ref
+from repro.kernels.arbiter.ref import (priority_arbiter_ref, srpt_topk_ref,
+                                      srpt_topk_rounds)
 
 
 # ------------------------------------------------------------ attention ----
@@ -156,16 +157,47 @@ def test_arbitrate_matches_ring_drain_select(H, cap, seed, p_elig):
         np.testing.assert_array_equal(np.asarray(bi), np.asarray(slot_idx))
 
 
-@pytest.mark.parametrize("H,M,K", [(8, 512, 7), (16, 1024, 4), (4, 128, 1),
-                                   (8, 512, 8), (13, 60, 5)])
-def test_topk_matches_ref(H, M, K):
-    rng = np.random.default_rng(H + M + K)
-    keys = jnp.asarray(rng.integers(0, 1 << 28, (H, M)), jnp.int32)
-    keys = jnp.where(jnp.asarray(rng.random((H, M)) < 0.5), keys, 0)
-    vals, idx = arb_ops.topk(keys, K, interpret=True)
+def _assert_topk_forms_match_ref(keys, K):
+    """Every top-K form equals ``srpt_topk_ref``: the pallas kernel
+    (interpret mode), the reference rounds, and the reference backend's
+    dispatch, whichever of the two reference forms it picks."""
     rv, ri = srpt_topk_ref(keys, K)
-    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rv))
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ri))
+    forms = {"pallas": arb_ops.topk(keys, K, interpret=True),
+             "rounds": srpt_topk_rounds(keys, K),
+             "reference": dispatch.topk(keys, K, backend="reference")}
+    for form, (vals, idx) in forms.items():
+        np.testing.assert_array_equal(np.asarray(vals), np.asarray(rv),
+                                      err_msg=form)
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(ri),
+                                      err_msg=form)
+
+
+# random: half the keys zero; ties: positive keys from {1, 2, 3}, so
+# every row is full of positive ties, with a fifth zero; zero: all-zero
+# rows (nothing eligible)
+@pytest.mark.parametrize("H,M,K,kind", [
+    pytest.param(8, 512, 7, "random", id="8-512-7"),
+    pytest.param(16, 1024, 4, "random", id="16-1024-4"),
+    pytest.param(4, 128, 1, "random", id="4-128-1"),
+    pytest.param(8, 512, 8, "random", id="8-512-8"),
+    pytest.param(13, 60, 5, "random", id="13-60-5"),
+    pytest.param(8, 300, 7, "ties", id="ties-8-300-7"),
+    pytest.param(6, 64, 7, "zero", id="zero-6-64-7"),
+    pytest.param(5, 3, 6, "ties", id="short-5-3-6"),
+    pytest.param(9, 200, 1, "ties", id="k1-9-200-1"),
+    pytest.param(7, 24, 24, "ties", id="kM-7-24-24"),
+])
+def test_topk_matches_ref(H, M, K, kind):
+    rng = np.random.default_rng(H + M + K)
+    if kind == "random":
+        keys = jnp.asarray(rng.integers(0, 1 << 28, (H, M)), jnp.int32)
+        keys = jnp.where(jnp.asarray(rng.random((H, M)) < 0.5), keys, 0)
+    elif kind == "ties":
+        keys = jnp.asarray(rng.integers(1, 4, (H, M)), jnp.int32)
+        keys = jnp.where(jnp.asarray(rng.random((H, M)) < 0.8), keys, 0)
+    else:
+        keys = jnp.zeros((H, M), jnp.int32)
+    _assert_topk_forms_match_ref(keys, K)
 
 
 def test_topk_short_rows_use_ineligible_sentinel():
@@ -189,11 +221,73 @@ def test_topk_short_rows_use_ineligible_sentinel():
 
 @settings(max_examples=20, deadline=None)
 @given(st.integers(1, 12), st.integers(1, 60), st.integers(1, 8),
-       st.integers(0, 2 ** 16))
-def test_topk_property(H, M, K, seed):
+       st.integers(0, 2 ** 16), st.sampled_from([3, 1 << 20]))
+def test_topk_property(H, M, K, seed, hi):
     rng = np.random.default_rng(seed)
-    keys = jnp.asarray(rng.integers(0, 1 << 20, (H, M)), jnp.int32)
-    vals, idx = arb_ops.topk(keys, K, interpret=True)
-    rv, ri = srpt_topk_ref(keys, K)
-    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rv))
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ri))
+    keys = jnp.asarray(rng.integers(0, hi, (H, M)), jnp.int32)
+    _assert_topk_forms_match_ref(keys, K)
+
+
+def test_topk_forms_match_ref_on_a_midrun_homa_grant_matrix():
+    """The (144, 6,000) grant keys of Homa on 144 hosts with 6,000 W4
+    messages, stopped at slot 512 of an overloaded burst so that several
+    receivers hold more than K grantable messages."""
+    from repro.core import SimConfig, make_messages, protocols, simulate
+    table = make_messages("W4", n_hosts=144, load=16.0, n_messages=6000,
+                          slot_bytes=256, seed=3)
+    cfg = SimConfig(n_hosts=144, max_slots=512, protocol="homa",
+                    backend="reference")
+    res = simulate(cfg, table, return_state=True)
+    st, S = res.state, res.static
+    eligible = jnp.asarray((st["recv"] > 0) & (st["completion"] < 0))
+    mat, K = protocols.srpt_grant_matrix(cfg, st, S, eligible,
+                                         res.alloc.n_sched)
+    assert mat.shape == (144, 6000) and K == 7
+    assert dispatch.topk_rounds(K, 6000) == K
+    assert ((np.asarray(mat) > 0).sum(axis=1) > K).sum() >= 5
+    _assert_topk_forms_match_ref(mat, K)
+
+
+def test_simulate_is_the_same_with_the_reference_topk_sorting(monkeypatch):
+    """Homa over 1,024 messages selects its grants by rounds; forced onto
+    ``lax.top_k`` it ends in the same state, bit for bit."""
+    from repro.core import SimConfig, make_messages, sim, simulate
+    table = make_messages("W4", n_hosts=32, load=16.0, n_messages=1024,
+                          slot_bytes=256, seed=3)
+    cfg = SimConfig(n_hosts=32, max_slots=512, protocol="homa",
+                    backend="reference")
+    rounds = simulate(cfg, table, return_state=True)
+    assert dispatch.topk_rounds(rounds.alloc.n_sched, 1024) > 0
+    monkeypatch.setattr(dispatch, "topk_rounds", lambda *a, **k: 0)
+    sim._run.clear_cache()
+    try:
+        sorting = simulate(cfg, table, return_state=True)
+    finally:
+        sim._run.clear_cache()
+    assert (rounds.completion >= 0).sum() > 20
+    for k, v in rounds.state.items():
+        np.testing.assert_array_equal(np.asarray(sorting.state[k]),
+                                      np.asarray(v), err_msg=k)
+
+
+def _crossover(M):
+    return max(K for K in range(1, 400) if dispatch.topk_rounds(K, M))
+
+
+@pytest.mark.parametrize("M,K", [(6000, 1), (6000, 7), (6000, "max"),
+                                 (6000, "max+1"), (512, "max"),
+                                 (512, "max+1")])
+def test_reference_topk_sorts_only_above_the_crossover(M, K):
+    """The reference top-K lowers to rounds (no sort, no top_k) for K at
+    or under the crossover of ``dispatch.topk_rounds`` and to
+    ``lax.top_k`` above it: the path choice the grant stage's speed
+    rests on."""
+    import re
+    if isinstance(K, str):
+        K = _crossover(M) + (K == "max+1")
+    text = jax.jit(lambda k: dispatch.topk(k, K, backend="reference")).lower(
+        jax.ShapeDtypeStruct((144, M), jnp.int32)).as_text()
+    ops = set(re.findall(r"\b(?:stablehlo|chlo|mhlo)\.[a-z_]+", text))
+    sorts = bool(ops & {"stablehlo.sort", "chlo.top_k"})
+    assert sorts == (dispatch.topk_rounds(K, M) == 0)
+    assert sorts == (K > _crossover(M))

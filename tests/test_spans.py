@@ -15,7 +15,9 @@ import pytest
 from repro.core import (FabricConfig, FaultConfig, SimConfig, StreamSpec,
                         SweepSpec, TraceConfig, make_messages, run_sweep,
                         simulate, sim, sweep, telemetry)
+from repro.core.priorities import PriorityAllocation
 from repro.core.protocols import get_protocol
+from repro.kernels.arbiter import dispatch
 
 SIM_CHILDREN = ["sim.prepare", "sim.init_state", "sim.dispatch",
                 "sim.scan_wait", "sim.fetch", "sim.finalize"]
@@ -60,7 +62,9 @@ def test_simulate_records_named_spans_nested_by_call():
     first, second = spans[:7], spans[7:]
     tops = [_check_nesting(c, "sim.simulate", SIM_CHILDREN)
             for c in (first, second)]
-    assert [t["counts"] for t in tops] == [{"slots": 300}] * 2
+    assert [t["counts"]["slots"] for t in tops] == [300] * 2
+    assert all(set(t["counts"]) == {"slots", "grant_topk_rounds"}
+               for t in tops)
     assert len({s["call"] for s in first}) == 1
     assert first[0]["call"] != second[0]["call"]
 
@@ -78,7 +82,32 @@ def test_run_sweep_records_named_spans_per_group(streaming):
     call = _call(telemetry.host_spans(), "sweep.run")
     top = _check_nesting(call, "sweep.run",
                          ["sweep.prepare"] + GROUP_CHILDREN)
-    assert top["counts"] == {"slots": 3 * 300}
+    assert top["counts"]["slots"] == 3 * 300
+    assert set(top["counts"]) == {"slots", "grant_topk_rounds"}
+
+
+# the allocation of the homa_w4 benchmark cell: one unscheduled level,
+# seven scheduled ones, so Homa's K is 7
+CELL_ALLOC = PriorityAllocation(n_prios=8, n_unsched=1, cutoffs=(),
+                                unsched_bytes_frac=0.040)
+
+
+@pytest.mark.parametrize("proto,rounds", [("homa", 7), ("phost", 1),
+                                          ("pfabric", 0)])
+def test_grant_topk_rounds_counts_the_compiled_selection(proto, rounds):
+    """``grant_topk_rounds`` on ``sim.simulate`` and ``sweep.run``: K
+    where the reference grant top-K runs by rounds, 0 for a protocol
+    with no grant top-K."""
+    telemetry.clear_spans()
+    cfg = _cfg(protocol=proto, max_slots=100, backend="reference")
+    tables = (_table(0, n_messages=256), _table(1, n_messages=256))
+    simulate(cfg, tables[0], alloc=CELL_ALLOC)
+    run_sweep(cfg, SweepSpec(tables=tables, alloc=CELL_ALLOC))
+    spans = telemetry.host_spans()
+    for root in ("sim.simulate", "sweep.run"):
+        top = _call(spans, root)[-1]
+        assert top["counts"]["grant_topk_rounds"] == rounds
+    assert dispatch.topk_rounds(7, 256) == 7
 
 
 def test_span_record_is_bounded():
