@@ -4,8 +4,9 @@ A mix names its entry: ``simulate`` (one run per call, the answer is the
 completion slot of every message) or ``run_sweep`` (a batch of runs per
 call, streaming statistics: the answer per run is its count of completed
 messages and its (size, slowdown) histogram). The same answers come from
-the plain reference (``bench/reference.py``) for the comparison that
-decides ``correct``.
+the configuration's plain reference (``cells.reference_module``:
+``bench/reference.py`` unless the configuration names another) for the
+comparison that decides ``correct``.
 
 No backend is named here: the program runs the arbitration backend it
 resolves by default, which is what its users get.
@@ -14,7 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import reference
+from bench import cells
 
 
 def sim_config(config: dict, mix: dict):
@@ -80,9 +81,12 @@ class Program:
 
 
 def reference_answers(config: dict, mix: dict, alloc_sizes, tables,
-                      devices, *, strict_priority: bool = True) -> list:
-    """The answers of the plain reference for ``tables``, the runs spread
-    over ``devices`` so that they run side by side."""
+                      devices, *, strict_priority: bool = True,
+                      ref=None) -> list:
+    """The answers of the plain reference ``ref`` (default: the
+    configuration's, ``cells.reference_module(config)``) for ``tables``,
+    the runs spread over ``devices`` so that they run side by side."""
+    reference = ref or cells.reference_module(config)
     sim = config["sim"]
     alloc = reference.priority_allocation(
         alloc_sizes, sim["rtt_slots"] * sim["slot_bytes"], sim["n_prios"])

@@ -1,7 +1,11 @@
 """Traffic generation: open-loop Poisson message tables from a mix file.
 
 A mix file (``bench/traffic/<name>.json``) holds the parameters; this
-module is the one generator that reads them. Message sizes are drawn
+module is the generator of its ``kind``, ``poisson``. A mix of any other
+kind ``K`` is drawn by ``table`` of ``bench/kinds/K.py``, which takes
+the arguments of ``poisson_table`` and returns the same four arrays; the
+streams it is given, and the allocation sample from ``size_bins``, are
+the same for every kind. Message sizes are drawn
 from a mixture of log-uniform bins (``size_bins``: probability, lowest
 and highest byte count), arrivals are Poisson at ``load`` times the
 aggregate host link rate, and sources and destinations are uniform with
@@ -17,7 +21,11 @@ from its own stream.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 import numpy as np
+
+from bench import cells
 
 WARMUP_CALL = 1 << 32           # call index of the set-up's warm-up call
 ALLOC_STREAM = (1 << 32) + 1    # rng stream of the allocation size sample
@@ -60,10 +68,20 @@ def poisson_table(mix: dict, n_hosts: int, slot_bytes: int,
             "size": sizes, "arrival": arrivals.astype(np.int32)}
 
 
+def table_fn(mix: dict, root: Path = cells.ROOT):
+    """The table generator of the mix's ``kind``: ``poisson_table``, or
+    ``table`` of ``bench/kinds/<kind>.py`` under ``root``."""
+    kind = mix.get("kind", "poisson")
+    return poisson_table if kind == "poisson" \
+        else cells.kind_table(kind, root)
+
+
 def call_tables(mix: dict, n_hosts: int, slot_bytes: int, seed: int,
-                call: int) -> list[dict]:
-    """The ``mix["runs_per_call"]`` tables of call ``call`` of a run."""
-    return [poisson_table(mix, n_hosts, slot_bytes, rng(seed, call, i))
+                call: int, table=None) -> list[dict]:
+    """The ``mix["runs_per_call"]`` tables of call ``call`` of a run, drawn
+    by ``table`` (default: ``table_fn(mix)``)."""
+    table = table or table_fn(mix)
+    return [table(mix, n_hosts, slot_bytes, rng(seed, call, i))
             for i in range(int(mix.get("runs_per_call", 1)))]
 
 

@@ -8,9 +8,12 @@ priority allocation from the seed, one warm-up call of the cell's entry
 at its own shapes, which compiles or reads JAX's persistent cache), then
 drives whole calls of the entry back to back for ``--seconds``. It
 starts no call after that and finishes the one in flight. After the
-window it compares one call, drawn from the seed, with the plain
-reference (``bench/reference.py``) and prints the numbers compared
-beside their limits, last on standard error and last in the result.
+window it compares one call, drawn from the seed, with the
+configuration's plain reference (``bench/reference.py`` unless the
+configuration names another) and prints the numbers compared beside
+their limits, last on standard error and last in the result. A named
+reference or traffic kind that cannot be loaded stops the run before
+set-up.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 records a profiler trace of the window and reports the per-layer
@@ -101,7 +104,8 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     args = ap.parse_args(argv)
 
     c = cells.cell(args.workload, root)
-    work, config, mix = c["workload"], c["config"], c["mix"]
+    work, config, mix, table = c["workload"], c["config"], c["mix"], \
+        c["table"]
     chips = int(work["chips"])
 
     import jax
@@ -124,7 +128,8 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
 
     counter = CompileCounter()
     t0 = time.perf_counter()
-    program.call(gen.call_tables(mix, H, sb, args.seed, gen.WARMUP_CALL))
+    program.call(gen.call_tables(mix, H, sb, args.seed, gen.WARMUP_CALL,
+                                 table))
     warm_s = time.perf_counter() - t0
     warm_compiles = counter.n
 
@@ -140,7 +145,7 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     if tracer:
         tracer.start()
     while time.perf_counter() - t_open < args.seconds:
-        tables = gen.call_tables(mix, H, sb, args.seed, len(calls))
+        tables = gen.call_tables(mix, H, sb, args.seed, len(calls), table)
         if tracer:
             for t in tables:
                 tp = time.perf_counter()
@@ -155,6 +160,7 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     t_close = time.perf_counter()
     if tracer:
         tracer.stop()
+        log(f"trace written in {time.perf_counter() - t_close:.1f} s")
     window_s = t_close - t_open
     in_window = counter.n - n_before
     n_runs = len(calls) * runs
@@ -177,7 +183,8 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     tables, got = calls[k]
     del calls
     tr = time.perf_counter()
-    want = entries.reference_answers(config, mix, alloc_sizes, tables, devs)
+    want = entries.reference_answers(config, mix, alloc_sizes, tables, devs,
+                                     ref=c["reference"])
     checks, failed = entries.compare(mix["entry"], got, want)
     record["reference_s"] = time.perf_counter() - tr
     record["checked_call"] = k
@@ -187,6 +194,7 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
     result = {"correct": correct, "attempted": n_runs, "failed": failed,
               "device": device}
     if args.trace:
+        t_read = time.perf_counter()
         red = tracer.reduce()
         if device["platform"] != "cpu" and not red["summary"][
                 "busy_s_by_device"]:
@@ -196,10 +204,13 @@ def run(argv=None, *, root: Path = ROOT, require_tpu: bool = True,
         record["trace_summary"] = red["summary"]
         run_view = {"trace": red, "record": record}
         metrics = {}
+        t_readers = time.perf_counter()
         for m in c["per_layer"]:
             v = cells.metric_reader(m["name"], root)(run_view)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        log(f"trace reduced in {t_readers - t_read:.1f} s, read by the "
+            f"metrics in {time.perf_counter() - t_readers:.1f} s")
         device["busy_s"] = red["summary"]["busy_s"]
         device["window_s"] = red["summary"]["window_s"]
         result["metrics"] = metrics

@@ -43,6 +43,7 @@ SCOPES = ("fused_precompute", "grants", "sender_select", "route",
           "uplink_drain", "downlink_drain", "stats", "recovery",
           "post_step", "telemetry", "stream_fold")
 UNSCOPED = "unscoped"
+NESTING = {"while", "conditional", "call"}   # opcodes that run nested ops
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -206,14 +207,18 @@ def hlo_scopes(path: str, module: str, run_name: str) -> dict:
     serialized ``HloProto`` as a bytes stat. An instruction whose
     ``metadata.op_name`` names no scope takes the one scope its users
     have, if they agree: XLA leaves the scope off some ops (a
-    ``cumsum`` lowers to ops named only ``reduce_window_sum``). Empty
-    when the trace holds no such module.
+    ``cumsum`` lowers to ops named only ``reduce_window_sum``). A loop,
+    conditional or call never does: it runs every stage nested in it,
+    and a sweep's slot loop has only the histogram fold that follows it
+    (``stream_fold``) for a user. Empty when the trace holds no such
+    module.
 
     Field numbers: XSpace.planes 1; XPlane.name 2, .event_metadata 4
     (map entry value 2); XEventMetadata.name 2, .stats 5; XStat
     .bytes_value 6; HloProto.hlo_module 1; HloModuleProto.computations
     3; HloComputationProto.instructions 2; HloInstructionProto.name 1,
-    .metadata 7, .id 35, .operand_ids 36; OpMetadata.op_name 2."""
+    .opcode 2, .metadata 7, .id 35, .operand_ids 36; OpMetadata.op_name
+    2."""
     buf = memoryview(Path(path).read_bytes())
 
     def text(span):
@@ -237,16 +242,19 @@ def hlo_scopes(path: str, module: str, run_name: str) -> dict:
                                  if len(found) == 1 else None)
     if md is None:
         return {}
-    names, scope, users = {}, {}, {}
+    names, scope, users, nests = {}, {}, {}, set()
     for stat in _sub(buf, md, 5):
         for proto in _sub(buf, stat, 6):
             for mod in _sub(buf, proto, 1):
                 for comp in _sub(buf, mod, 3):
                     for inst in _sub(buf, comp, 2):
                         name, op_name, iid, operands = None, "", None, []
+                        nesting = False
                         for f, v in _fields(buf, *inst):
                             if f == 1:
                                 name = text(v)
+                            elif f == 2:
+                                nesting = text(v) in NESTING
                             elif f == 7:
                                 ops = _sub(buf, v, 2)
                                 op_name = text(ops[0]) if ops else ""
@@ -256,13 +264,15 @@ def hlo_scopes(path: str, module: str, run_name: str) -> dict:
                                 operands += _ints(buf, v)
                         names[iid] = name
                         scope[iid] = scope_of(op_name)
+                        if nesting:
+                            nests.add(iid)
                         for o in operands:
                             users.setdefault(o, []).append(iid)
     changed = True
     while changed:
         changed = False
         for iid, sc in scope.items():
-            if sc != UNSCOPED:
+            if sc != UNSCOPED or iid in nests:
                 continue
             got = {scope[u] for u in users.get(iid, ())} - {UNSCOPED}
             if len(got) == 1:

@@ -146,10 +146,24 @@ def _int(field, n):
     return _varint(field << 3) + _varint(n)
 
 
-def _inst(name, iid, op_name, operands):
+def _inst(name, iid, op_name, operands, opcode=""):
     meta = _msg(7, _msg(2, op_name)) if op_name else b""
-    return _msg(2, _msg(1, name) + meta + _int(35, iid) + _msg(
+    code = _msg(2, opcode) if opcode else b""
+    return _msg(2, _msg(1, name) + code + meta + _int(35, iid) + _msg(
         36, b"".join(_varint(o) for o in operands)))
+
+
+def _space(tmp_path, insts):
+    """An ``XSpace`` file whose metadata plane holds ``jit__run(42)``'s
+    ``HloProto`` of one computation of ``insts``."""
+    hlo = _msg(1, _msg(3, b"".join(insts)))
+    meta = _int(1, 9) + _msg(2, "jit__run(42)") + _msg(
+        5, _int(1, 7) + _msg(6, hlo))
+    space = _msg(1, _msg(2, "/host:metadata")
+                 + _msg(4, _int(1, 9) + _msg(2, meta)))
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space)
+    return str(path)
 
 
 def test_hlo_scopes_from_the_metadata_plane(tmp_path):
@@ -180,6 +194,22 @@ def test_hlo_scopes_from_the_metadata_plane(tmp_path):
     assert stages.hlo_scopes(str(path), "jit__run", "jit__run(42)") == want
     assert stages.hlo_scopes(str(path), "jit__run", "other") == want
     assert stages.hlo_scopes(str(path), "jit__sweep_batch", "") == {}
+
+
+def test_hlo_scopes_leave_a_loop_unscoped(tmp_path):
+    """A sweep's slot loop has only the histogram fold after it for a
+    user: the loop, which runs every stage, takes no scope from it,
+    while an ordinary op with the same user does."""
+    body = "jit(_sweep_batch)/vmap()/while/body/closed_call/"
+    path = _space(tmp_path, [
+        _inst("while.7", 1, "jit(_sweep_batch)/vmap()/while", [],
+              "while"),
+        _inst("copy.3", 2, "", [], "copy"),
+        _inst("fold", 3, body + "stream_fold/add", [1, 2], "add"),
+    ])
+    assert stages.hlo_scopes(path, "jit__run", "jit__run(42)") == {
+        "while.7": stages.UNSCOPED, "copy.3": "stream_fold",
+        "fold": "stream_fold"}
 
 
 def test_hlo_scopes_from_a_cpu_trace(tmp_path):
