@@ -11,9 +11,11 @@ changes."""
 from __future__ import annotations
 
 import functools
+import hashlib
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -33,10 +35,20 @@ def benchmark(root: Path = ROOT) -> dict:
 
 @functools.cache
 def _exec(path: Path):
-    spec = importlib.util.spec_from_file_location(f"bench_{path.stem}",
-                                                  path)
+    """The module at ``path``, registered in ``sys.modules`` while and
+    after it runs, under a name of its own path, so that what looks its
+    module up there (a dataclass under ``from __future__ import
+    annotations``, pickle) finds it."""
+    digest = hashlib.sha256(bytes(path)).hexdigest()[:12]
+    name = f"bench_{path.stem}_{digest}"
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
     return mod
 
 

@@ -1,7 +1,7 @@
 """Helpers for whole benchmark runs on the CPU at test size
-(``bench/tests/data/tiny``, with the fixtures of ``data/hooks``). Each
-run is its own process, as on the chip, with JAX's compile cache in a
-temporary directory."""
+(``bench/tests/data/tiny``, with the fixtures of ``data/hooks`` and
+``data/addition``). Each run is its own process, as on the chip, with
+JAX's compile cache in a temporary directory."""
 from __future__ import annotations
 
 import json
@@ -15,6 +15,7 @@ BENCH = Path(__file__).resolve().parents[1]
 REPO = BENCH.parent
 TINY = BENCH / "tests" / "data" / "tiny"
 HOOKS = BENCH / "tests" / "data" / "hooks"
+ADDITION = BENCH / "tests" / "data" / "addition"
 SEED = "3000000019"                # past 32 signed bits
 
 
@@ -57,6 +58,63 @@ def hooks_root(tmp_path_factory) -> Path:
                                    "config": "tiny16_homa",
                                    "traffic": f"tiny_{kind}", "chips": 1,
                                    "why": "test"})
+    (r / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+    return r
+
+
+# What ``addition_root`` appends to each list of ``BENCHMARK.json``.
+ADDED = {"configs": ["dry16_homa_torfail", "dry16_homa"],
+         "workloads": ["homa_dry_torfail", "homa_dry"],
+         "per_layer": ["dry_call_ms"]}
+# Rack 0's TOR dark for slots [512, 1024), with the paper's section 3.7
+# recovery timers, as a configuration file states it.
+TOR_FAULTS = {"tor_fail": [[0, 512, 1024]], "resend_slots": 300,
+              "sender_timeout_slots": 760}
+
+
+def addition_root(tmp_path_factory) -> Path:
+    """A copy of the real ``BENCHMARK.json`` and ``bench/`` with one
+    deployment added as a ``model_config`` change adds it: new files and
+    entries appended to ``BENCHMARK.json``, nothing else edited. The
+    configuration ``dry16_homa_torfail`` (the tiny 16-host Homa with a
+    ``fabric.faults`` block: rack 0's TOR fails) names its own reference,
+    ``bench/references/dry_same.py`` (the fixture ``same``: a re-export
+    of ``bench/reference.py``, which models no fault); its fault-free
+    twin ``dry16_homa`` names the same file and is the one a whole run
+    can judge. Each has a cell (``homa_dry_torfail``, ``homa_dry``) on
+    the new mix ``dry_single``, and the new reader
+    ``bench/metrics/dry_call_ms.py`` is listed for those two cells
+    alone."""
+    r = tmp_path_factory.mktemp("addition")
+    shutil.copy(REPO / "BENCHMARK.json", r)
+    shutil.copytree(BENCH, r / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (r / "bench" / "references").mkdir(exist_ok=True)
+    shutil.copy(HOOKS / "references" / "same.py",
+                r / "bench" / "references" / "dry_same.py")
+    shutil.copy(ADDITION / "metrics" / "dry_call_ms.py",
+                r / "bench" / "metrics" / "dry_call_ms.py")
+    shutil.copy(TINY / "bench" / "traffic" / "tiny_single.json",
+                r / "bench" / "traffic" / "dry_single.json")
+    base = json.loads((TINY / "bench/configs/tiny16_homa.json").read_text())
+    twin = {**base, "reference": "bench/references/dry_same.py"}
+    confs = {"dry16_homa_torfail": {**twin, "fabric": {
+        **base["fabric"], "faults": TOR_FAULTS}}, "dry16_homa": twin}
+    bench = json.loads((r / "BENCHMARK.json").read_text())
+    for name in ADDED["configs"]:
+        path = f"bench/configs/{name}.json"
+        (r / path).write_text(json.dumps(confs[name], indent=1))
+        bench["configs"].append({
+            "name": name, "source": "https://arxiv.org/abs/1803.09615",
+            "file": path, "reduced": base["reduced"], "why": "test"})
+    for name, conf in zip(ADDED["workloads"], ADDED["configs"]):
+        bench["workloads"].append({"name": name, "config": conf,
+                                   "traffic": "dry_single", "chips": 1,
+                                   "why": "test"})
+    bench["per_layer"].append({
+        "name": "dry_call_ms", "unit": "ms", "better": "lower",
+        "source": "host_clock", "layer": "entry (host)",
+        "moves": "run_slots_per_s", "workloads": ADDED["workloads"]})
     (r / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
     return r
 

@@ -6,17 +6,14 @@ that cannot be loaded stops the run; nothing falls back. The Poisson
 mixes draw the tables they drew before the hooks."""
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 from pathlib import Path
 
 import numpy as np
 import pytest
 
 from bench import cells, entries, gen
-from bench.tests.runs import REPO, SEED, drive, hooks_root, launch
+from bench.tests import checks
+from bench.tests.runs import SEED, drive, hooks_root, launch
 
 CONFIGS = sorted((cells.BENCH / "configs").glob("*.json"))
 MIXES = ["w4_load80_single", "w4_load80_sweep8x4"]
@@ -29,8 +26,10 @@ def root(tmp_path_factory):
 
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
 def test_configs_resolve_to_the_default_reference(path):
-    mod = cells.reference_module(cells.load_json(path))
-    assert Path(mod.__file__).resolve() == cells.BENCH / "reference.py"
+    """Each configuration resolves to its reference: ``bench/
+    reference.py`` without a ``"reference"`` key, the named file with
+    one."""
+    checks.config_resolves_to_its_reference(cells.ROOT, path)
 
 
 @pytest.mark.parametrize("ref, correct", [("same", True),
@@ -59,38 +58,51 @@ def test_broken_reference_stops_the_run_before_setup(root, ref, says):
     assert "[bench]" not in p.stderr
 
 
+DATACLASS_REFERENCE = """from __future__ import annotations
+import dataclasses
+from bench.reference import priority_allocation, simulate, slowdown_hist
+
+
+@dataclasses.dataclass(frozen=True)
+class Window:
+    rack: int
+    start: int
+    end: int
+"""
+
+
+def test_reference_defining_a_dataclass_loads_by_path(tmp_path):
+    """A reference loaded by path may define a dataclass under ``from
+    __future__ import annotations`` (which looks its module up in
+    ``sys.modules``); files of one name in two directories load as two
+    modules."""
+    for d in ("references", "other"):
+        (tmp_path / "bench" / d).mkdir(parents=True)
+        (tmp_path / "bench" / d / "windows.py").write_text(
+            DATACLASS_REFERENCE)
+    mods = [cells.reference_module(
+        {"reference": f"bench/{d}/windows.py"}, tmp_path)
+        for d in ("references", "other")]
+    assert mods[0].Window(0, 2048, 4096).end == 4096
+    assert mods[0] is not mods[1]
+    assert mods[0].__name__ != mods[1].__name__
+
+
 def test_reference_path_outside_the_checkout_is_refused():
     with pytest.raises(SystemExit, match="not a path inside"):
         cells.reference_module({"reference": "../reference.py"})
 
 
-def _repro_modules(config: Path, root: Path) -> list[str]:
-    """The modules of the program loaded by loading the configuration's
-    reference in a fresh process."""
-    code = ("import json, sys; from pathlib import Path; "
-            "from bench import cells; "
-            "cells.reference_module(json.load(open(sys.argv[1])), "
-            "Path(sys.argv[2])); "
-            "print(json.dumps(sorted(m for m in sys.modules "
-            "if m.split('.')[0] == 'repro')))")
-    p = subprocess.run([sys.executable, "-c", code, str(config), str(root)],
-                       capture_output=True, text=True, timeout=300,
-                       cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu",
-                                      "PYTHONPATH": str(REPO / "src")})
-    assert p.returncode == 0, p.stderr[-3000:]
-    return json.loads(p.stdout.strip().splitlines()[-1])
-
-
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
 def test_references_of_the_configurations_import_nothing_of_the_program(
         path):
-    assert _repro_modules(path, cells.ROOT) == []
+    checks.reference_imports_nothing_of_the_program(cells.ROOT, path)
 
 
 @pytest.mark.parametrize("ref", ["same", "strict_off"])
 def test_fixture_references_import_nothing_of_the_program(root, ref):
     path = root / "bench" / "configs" / f"tiny16_homa_{ref}.json"
-    assert _repro_modules(path, root) == []
+    checks.reference_imports_nothing_of_the_program(root, path)
 
 
 def test_kind_tables_reach_program_and_reference(root):

@@ -4,16 +4,12 @@ benchmark's format, and the traffic generator draws what the repository's
 own generator draws for the same seed."""
 from __future__ import annotations
 
-import json
-import re
-
 import numpy as np
 import pytest
 
 from bench import cells, gen
+from bench.tests import checks
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 BENCH = cells.benchmark()
 WORKLOADS = [w["name"] for w in BENCH["workloads"]]
 
@@ -28,39 +24,12 @@ def test_top_level_keys():
 
 
 def test_names_units_and_entries():
-    names = []
-    for c in BENCH["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert c["file"].startswith("bench/")
-        names.append(c["name"])
-    for w in BENCH["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
-        names.append(w["name"])
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        names.append(m["name"])
-    for m in BENCH["end_to_end"]:
-        assert 0.01 <= m["bound"] <= 0.25
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in BENCH["per_layer"]:
-        assert m["moves"] in {e["name"] for e in BENCH["end_to_end"]}
-    assert all(NAME.match(n) for n in names)
-    assert len(set(names)) == len(names)
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) \
-        <= max(1, len(BENCH["workloads"]) // 2)
+    checks.names_units_and_entries(cells.ROOT)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_cell_pieces_are_found_by_name(workload):
-    c = cells.cell(workload)
-    assert c["mix"]["entry"] in ("simulate", "run_sweep")
-    assert {m["name"] for m in c["end_to_end"]} >= {"run_slots_per_s",
-                                                    "setup_s"}
-    assert "backend" not in c["config"]["sim"]
-    assert "backend" not in json.dumps(c["mix"])
-    for m in c["per_layer"]:
-        assert callable(cells.metric_reader(m["name"]))
+    checks.cell_pieces_are_found_by_name(cells.ROOT, workload)
 
 
 def test_unknown_workload_is_refused():
@@ -68,15 +37,9 @@ def test_unknown_workload_is_refused():
         cells.cell("no_such_cell")
 
 
-EMPTY = {"trace": {"summary": {"module_s": {}, "idle_share_by_device": {},
-                               "busy_s_by_device": {}}},
-         "record": {"prepare_s": [], "entry": "simulate", "runs": 0,
-                    "slots_per_run": 8192}}
-
-
 @pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
 def test_reader_with_nothing_to_read_returns_none(metric):
-    assert cells.metric_reader(metric)(EMPTY) is None
+    checks.reader_with_nothing_to_read_returns_none(cells.ROOT, metric)
 
 
 def test_readers_compute_their_metrics():
