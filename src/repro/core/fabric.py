@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -196,6 +195,12 @@ def spine_hash(src: np.ndarray, dst: np.ndarray, msg_id: np.ndarray,
 # Shared by downlink and uplink tiers: a (R, cap) pool of ring buffers
 # with occupancy-based insertion and strict-priority / FIFO drain.
 
+# Block width of ring_insert's two-level search: one TPU lane row. JAX
+# lowers cumsum as a reduce_window as wide as its axis (O(width^2) adds
+# a row), so both running sums stay short: at cap 4096, 32 blocks of 128.
+RING_BLOCK = 128
+
+
 def ring_insert(msg_a, prio_a, seq_a, valid_a, row, ok, msg, prio, seq):
     """Insert up to ``len(row)`` chunks into per-row rings.
 
@@ -203,21 +208,34 @@ def ring_insert(msg_a, prio_a, seq_a, valid_a, row, ok, msg, prio, seq):
     target the same row in one slot (they take consecutive free slots in
     input order). A chunk is dropped only when its ring is actually
     full. Returns the four updated ring arrays plus the dropped count.
+
+    The item of rank r among its row's items takes the row's (r+1)-th
+    free buffer, the lowest-numbered one first. It is found in two
+    levels: the free counts of the row's ``RING_BLOCK``-wide blocks and
+    their running sum name the block that holds it, and a running sum
+    inside that one block names the buffer. A ring whose ``cap`` is not
+    a multiple of the block is padded with occupied buffers, which are
+    never taken.
     """
-    R = valid_a.shape[0]
+    R, cap = valid_a.shape
     n = row.shape[0]
     rows = jnp.where(ok, row, R)                              # sentinel R
     same = (rows[:, None] == rows[None, :]) & ok[None, :] & ok[:, None]
     ar = jnp.arange(n)
     rank = jnp.sum(same & (ar[None, :] < ar[:, None]), axis=1)
-    # (r+1)-th free slot per row: the cumsum of free slots is
-    # nondecreasing, so a binary search per item replaces the full
-    # (R, cap, n) match table (see sim.py history).
-    c = jnp.cumsum(~valid_a, axis=1)
-    c_row = c[jnp.minimum(rows, R - 1)]                       # (n, cap)
-    room = c_row[:, -1] > rank
+    nb = -(-cap // RING_BLOCK)
+    free = jnp.pad(~valid_a, ((0, 0), (0, nb * RING_BLOCK - cap)))
+    free = free.reshape(R, nb, RING_BLOCK)
+    r = jnp.minimum(rows, R - 1)
+    cb_row = jnp.cumsum(jnp.sum(free, axis=2, dtype=I32), axis=1)[r]
+    room = cb_row[:, -1] > rank                               # (n,)
     okw = ok & room
-    pos = jax.vmap(jnp.searchsorted)(c_row, rank + 1)         # (n,)
+    # block of the (rank+1)-th free buffer, and the free buffers before it
+    below = cb_row <= rank[:, None]                           # (n, nb)
+    blk = jnp.minimum(jnp.sum(below, axis=1), nb - 1)
+    want = rank + 1 - jnp.max(jnp.where(below, cb_row, 0), axis=1)
+    ci = jnp.cumsum(free[r, blk].astype(I32), axis=1)         # (n, B)
+    pos = blk * RING_BLOCK + jnp.sum(ci < want[:, None], axis=1)
     # suppressed writes go out of bounds (mode="drop"): an in-bounds
     # no-op write could race a genuine insertion at the same location
     idx = (jnp.where(okw, rows, R), jnp.where(okw, pos, 0))

@@ -5,11 +5,15 @@ a snapshot of the pre-fabric simulator's outputs: the fabric tier must be
 invisible unless explicitly enabled.
 """
 import json
+import re
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import fabric as fab
 from repro.core import (SimConfig, FabricConfig, SweepSpec, simulate,
                         run_sweep, make_messages, scenarios)
 
@@ -288,3 +292,104 @@ def test_prepare_rejects_oversized_inputs_with_valueerror():
                           slot_bytes=256, seed=0)
     with pytest.raises(ValueError, match="max_slots"):
         simulate(SimConfig(n_hosts=4, max_slots=2 ** 21), small)
+
+
+# ------------------------------------------------------- ring insert ------
+
+def _insert_oracle(msg_a, prio_a, seq_a, valid_a, row, ok, msg, prio, seq):
+    """Item by item, in input order: the lowest-numbered free buffer of
+    the item's row; dropped only when that row is full."""
+    m, p, s, v = (np.array(a) for a in (msg_a, prio_a, seq_a, valid_a))
+    dropped = 0
+    for i in np.flatnonzero(ok):
+        free = np.flatnonzero(~v[row[i]])
+        if free.size == 0:
+            dropped += 1
+            continue
+        j = free[0]
+        m[row[i], j], p[row[i], j], s[row[i], j] = msg[i], prio[i], seq[i]
+        v[row[i], j] = True
+    return m, p, s, v, dropped
+
+
+def _insert_case(cap, fill, layout, seed, R=12, n=48):
+    """Rings and items for one case. ``spread``: random fill, items on
+    random rows; ``last_free``: every row full but one buffer of its
+    last block (the last buffer on even rows); ``crowded``: all items on
+    one row that has n // 2 free buffers. About a fifth of the items are
+    masked, some of those on the sentinel row ``R``."""
+    rng = np.random.default_rng(seed)
+    if layout == "spread":
+        valid = rng.random((R, cap)) < fill
+        row = rng.integers(0, R, n)
+    elif layout == "last_free":
+        valid = np.ones((R, cap), bool)
+        lo = (cap - 1) // fab.RING_BLOCK * fab.RING_BLOCK
+        for r in range(R):
+            valid[r, cap - 1 if r % 2 == 0 else rng.integers(lo, cap)] = False
+        row = rng.integers(0, R, n)
+    else:
+        valid = rng.random((R, cap)) < fill
+        valid[3] = True
+        valid[3, rng.choice(cap, n // 2, replace=False)] = False
+        row = np.full(n, 3)
+    ok = rng.random(n) >= 0.2
+    row = np.where(~ok & (rng.random(n) < 0.5), R, row)
+    ring = [np.where(valid, rng.integers(0, 99, (R, cap)), -1)
+            for _ in range(3)]
+    items = [rng.integers(0, 99, n) for _ in range(3)]
+    return (*ring, valid, row, ok, *items)
+
+
+def _as_jax(case):
+    return [jnp.asarray(a, bool if a.dtype == bool else jnp.int32)
+            for a in case]
+
+
+_INSERT_CASES = (
+    [(cap, fill, "spread") for cap in (24, 100, 128, 300, 4096)
+     for fill in (0.0, 0.5, 0.97, 1.0)]
+    + [(cap, None, "last_free") for cap in (24, 100, 128, 300, 4096)]
+    + [(cap, 0.5, "crowded") for cap in (100, 4096)])
+
+
+@pytest.mark.parametrize("batch", [None, 2])
+@pytest.mark.parametrize("cap,fill,layout", _INSERT_CASES)
+def test_ring_insert_takes_lowest_free_buffer_in_input_order(
+        cap, fill, layout, batch):
+    """Every item gets its row's lowest free buffer, in input order, and
+    is dropped only when its row is full: the drain breaks (priority,
+    seq) ties by buffer index, so positions must match exactly. With
+    ``batch`` the rings and items are vmapped, as a sweep runs them."""
+    seeds = [0] if batch is None else range(batch)
+    cases = [_insert_case(cap, fill, layout, seed=s + 1000 * cap)
+             for s in seeds]
+    if batch is None:
+        got = [jax.jit(fab.ring_insert)(*_as_jax(cases[0]))]
+    else:
+        stacked = _as_jax([np.stack(a) for a in zip(*cases)])
+        out = jax.jit(jax.vmap(fab.ring_insert))(*stacked)
+        got = [[a[b] for a in out] for b in range(batch)]
+    for case, res in zip(cases, got):
+        want = _insert_oracle(*case)
+        for name, g, w in zip(("msg", "prio", "seq", "valid", "dropped"),
+                              res, want):
+            np.testing.assert_array_equal(np.asarray(g), w, err_msg=name)
+
+
+def test_ring_insert_lowers_without_loops_or_full_row_cumsum():
+    """At the paper's ring shape, (144, 4096) with 144 items, the insert
+    lowers with no ``while`` and no ``reduce_window`` wider than one
+    block: JAX lowers ``cumsum`` as a ``reduce_window`` as wide as the
+    axis, O(cap^2) adds per row, which is what made the insert slow."""
+    R, cap, n = 144, 4096, 144
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)   # noqa: E731
+    b = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)     # noqa: E731
+    txt = jax.jit(fab.ring_insert).lower(
+        i32(R, cap), i32(R, cap), i32(R, cap), b(R, cap),
+        i32(n), b(n), i32(n), i32(n), i32(n)).as_text()
+    assert "stablehlo.while" not in txt
+    windows = re.findall(r"window_dimensions = array<i64: ([\d, ]+)>", txt)
+    assert windows
+    assert max(int(w) for dims in windows for w in dims.split(",")) \
+        <= fab.RING_BLOCK
